@@ -18,6 +18,7 @@ import time
 import traceback
 
 from benchmarks.common import print_table
+from repro.launch import init_compile_cache
 
 #: (module, paper artifact)
 SUITE = [
@@ -49,6 +50,7 @@ def main() -> None:
         help="toy-scale pass over every benchmark (CI rot check)",
     )
     args = ap.parse_args()
+    init_compile_cache()
 
     if args.only and args.only not in {name for name, _ in SUITE}:
         ap.error(

@@ -48,7 +48,6 @@ results are unchanged at lower wall-clock.
 """
 from __future__ import annotations
 
-import sys
 from dataclasses import dataclass, field
 from typing import Protocol, runtime_checkable
 
@@ -302,10 +301,10 @@ class TrainingSimulator:
     placement: list[int] = field(default_factory=list)
     #: per-DP-group micro-batch counts (S2); default: even split
     allocation: list[int] = field(default_factory=list)
-    #: reduction backend: "auto" (pallas on a compiled jax backend, else
-    #: the inline vectorized numpy path), a registry name ("reference" /
-    #: "vectorized" / "pallas"), or a ReductionBackend instance — see
-    #: REDUCTION_BACKENDS and docs/kernels.md
+    #: reduction backend: "auto" (pallas on a TPU for a full hybrid job,
+    #: else the inline vectorized numpy path), a registry name
+    #: ("reference" / "vectorized" / "pallas"), or a ReductionBackend
+    #: instance — see resolve_reduction_backend and docs/kernels.md
     reduction: object = "auto"
     state: ClusterState = field(init=False)
 
@@ -330,7 +329,7 @@ class TrainingSimulator:
         if name in ("placement", "allocation", "state", "job", "cluster",
                     "reduction"):
             d["_cfg_ver"] = d.get("_cfg_ver", 0) + 1
-        if name == "reduction":
+        if name in ("reduction", "job"):
             d["_red_obj"] = False  # unresolved; None = inline vectorized
         if name in ("allocation", "job"):
             d["_alloc_arr"] = None  # caches allocation + pp - 1
@@ -822,13 +821,20 @@ class TrainingSimulator:
         """The resolved :data:`REDUCTION_BACKENDS` instance, or None for
         the inline vectorized fast path (the hot-path default — no
         per-call indirection). Resolved lazily, re-resolved whenever the
-        ``reduction`` field is reassigned."""
+        ``reduction`` or ``job`` field is reassigned."""
         d = self.__dict__
         obj = d.get("_red_obj", False)
         if obj is False:
-            obj = resolve_reduction_backend(self.reduction)
+            obj = resolve_reduction_backend(self.reduction, self.job)
             d["_red_obj"] = obj
         return obj
+
+    @property
+    def reduction_name(self) -> str:
+        """Registry name of the backend this simulator's reductions run on
+        (``"vectorized"`` for the inline path) — what a run reports."""
+        rb = self._reduction_backend()
+        return "vectorized" if rb is None else rb.name
 
     def iteration_time(self) -> float:
         key = (self.__dict__["_cfg_ver"], self.state.version)
@@ -1305,7 +1311,7 @@ class ReferenceReduction:
 class VectorizedReduction:
     """The numpy fast path as an explicit backend object.
 
-    ``sim.reduction = "vectorized"`` (and "auto" on a CPU-only jax) skips
+    ``sim.reduction = "vectorized"`` (and "auto" off a TPU) skips
     this object entirely and runs the same code inline — this class exists
     so the equivalence suite can drive every registry entry uniformly.
     """
@@ -1328,10 +1334,10 @@ class PallasReduction:
     per evaluation (memoized on the simulator's config/state versions).
 
     Measurement (and its event-scoped incremental maintenance) stays on
-    the numpy side; the kernel fuses every reduction after it. Degenerate
-    topologies (any of tp/dp/pp == 1) fall back to the vectorized path.
-    ``tolerance`` reflects float32 kernel arithmetic against the float64
-    oracle (see docs/kernels.md).
+    the numpy side; the kernel fuses every reduction after it. It needs a
+    full hybrid topology (:func:`fits_pallas_reduction`); resolution
+    refuses it for any other. ``tolerance`` reflects float32 kernel
+    arithmetic against the float64 oracle (see docs/kernels.md).
     """
 
     name = "pallas"
@@ -1345,42 +1351,34 @@ class PallasReduction:
         key = (d["_cfg_ver"], sim.state.version)
         if d.get("_red_key") == key:
             return d["_red_val"]
-        c = sim._cells()
-        if c.tp_edge is None or c.dp_edge is None or c.hop_bw is None:
-            out = None
-        else:
-            from repro.kernels.cell_reduce import cell_reduce
+        if not fits_pallas_reduction(sim.job):
+            raise ValueError(_degenerate_msg(sim.job))
+        from repro.kernels.cell_reduce import cell_reduce
 
-            t, stage_max, tp_bw, dp_bw = cell_reduce(
-                c.cell_speed, c.tp_edge, c.dp_edge, c.hop_bw,
-                sim._alloc_off(), c.c_flops, c.c_speed, c.c_tp,
-                c.pp_vol, c.c_dp, interpret=self.interpret,
-            )
-            out = (
-                float(t[0, 0]),
-                [float(v) for v in np.asarray(stage_max[0])],
-                np.asarray(tp_bw, dtype=np.float64),
-                np.asarray(dp_bw, dtype=np.float64),
-            )
+        c = sim._cells()
+        t, stage_max, tp_bw, dp_bw = cell_reduce(
+            c.cell_speed, c.tp_edge, c.dp_edge, c.hop_bw,
+            sim._alloc_off(), c.c_flops, c.c_speed, c.c_tp,
+            c.pp_vol, c.c_dp, interpret=self.interpret,
+        )
+        out = (
+            float(t[0, 0]),
+            [float(v) for v in np.asarray(stage_max[0])],
+            np.asarray(tp_bw, dtype=np.float64),
+            np.asarray(dp_bw, dtype=np.float64),
+        )
         d["_red_key"] = key
         d["_red_val"] = out
         return out
 
     def iteration_time(self, sim: TrainingSimulator) -> float:
-        out = self._outs(sim)
-        return sim._vec_iteration_time() if out is None else out[0]
+        return self._outs(sim)[0]
 
     def per_microbatch_times(self, sim: TrainingSimulator) -> list[float]:
-        out = self._outs(sim)
-        if out is None:
-            return [float(v) for v in sim._cells().stage_max]
-        return list(out[1])
+        return list(self._outs(sim)[1])
 
     def profile_groups(self, sim: TrainingSimulator) -> dict[str, float]:
-        out = self._outs(sim)
-        if out is None:
-            return sim._vec_profile_groups()
-        _, _, tp_bw, dp_bw = out
+        _, _, tp_bw, dp_bw = self._outs(sim)
         lay = sim._layout()
         m = sim.job.model
         job = sim.job
@@ -1404,27 +1402,23 @@ REDUCTION_BACKENDS: dict[str, type] = {
 }
 
 
-def _pallas_compiled() -> bool:
-    """True when jax is loaded *and* targets a compiled (non-CPU) backend.
-
-    Deliberately checks ``sys.modules`` instead of importing jax: resolving
-    the default backend must not drag the jax runtime into every numpy-only
-    simulator process.
-    """
-    jax = sys.modules.get("jax")
-    if jax is None:
-        return False
-    try:
-        return jax.default_backend() != "cpu"
-    except Exception:  # pragma: no cover - uninitialized backends
-        return False
+def fits_pallas_reduction(job: JobSpec) -> bool:
+    """The fused reduction kernel needs every parallel axis > 1: a job
+    without TP rings, DP rings or PP hops has nothing to feed some of its
+    inputs."""
+    return job.tp > 1 and job.dp > 1 and job.pp > 1
 
 
-def select_reduction_backend(name: str | None = None):
-    """Instantiate a reduction backend by registry name; None/"auto" picks
-    ``pallas`` on a compiled jax backend and ``vectorized`` otherwise."""
-    if name in (None, "auto"):
-        name = "pallas" if _pallas_compiled() else "vectorized"
+def _degenerate_msg(job: JobSpec) -> str:
+    return (
+        f"the pallas reduction needs tp, dp and pp > 1; this job has "
+        f"tp={job.tp} dp={job.dp} pp={job.pp} (use 'vectorized')"
+    )
+
+
+def select_reduction_backend(name: str):
+    """Instantiate a reduction backend by registry name (``"auto"`` is
+    :func:`resolve_reduction_backend`'s, since it depends on the job)."""
     try:
         cls = REDUCTION_BACKENDS[name]
     except KeyError:
@@ -1435,19 +1429,30 @@ def select_reduction_backend(name: str | None = None):
     return cls()
 
 
-def resolve_reduction_backend(spec):
-    """``TrainingSimulator.reduction`` -> backend instance, or None for the
-    inline vectorized fast path ("auto" on CPU-only jax, "vectorized",
-    "numpy"). Accepts a registry name or a ready ReductionBackend
-    instance."""
+def resolve_reduction_backend(spec, job: JobSpec):
+    """``TrainingSimulator.reduction`` for ``job`` -> backend instance, or
+    None for the inline vectorized fast path.
+
+    ``None``/``"auto"`` picks ``pallas`` only on a TPU *and* for a full
+    hybrid job (:func:`fits_pallas_reduction`); every other case runs the
+    inline path, and :attr:`TrainingSimulator.reduction_name` reports
+    which. ``"vectorized"``/``"numpy"`` are the inline path. An explicit
+    ``pallas`` (name or instance) for a degenerate job raises
+    ``ValueError`` instead of quietly running something else.
+    """
     if spec in (None, "auto"):
-        return PallasReduction() if _pallas_compiled() else None
+        from repro.kernels import pallas_compiled
+
+        fits = fits_pallas_reduction(job) and pallas_compiled()
+        return PallasReduction() if fits else None
     if isinstance(spec, str):
         if spec in ("vectorized", "numpy"):
             return None
-        return select_reduction_backend(spec)
-    if hasattr(spec, "iteration_time"):
-        return spec
-    raise TypeError(
-        f"reduction must be a registry name or ReductionBackend, got {spec!r}"
-    )
+        spec = select_reduction_backend(spec)
+    elif not hasattr(spec, "iteration_time"):
+        raise TypeError(
+            f"reduction must be a registry name or ReductionBackend, got {spec!r}"
+        )
+    if isinstance(spec, PallasReduction) and not fits_pallas_reduction(job):
+        raise ValueError(_degenerate_msg(job))
+    return spec

@@ -1428,8 +1428,8 @@ class BatchedScreening(ScreeningBackendFactory):
 class PallasScreening(ScreeningBackendFactory):
     """Fused Pallas step kernel (``repro.kernels.bocd_step.PallasBOCD``).
 
-    ``interpret``/``dtype`` override the kernel defaults (interpret mode is
-    auto-enabled on CPU jax; dtype defaults to float32 — see
+    ``interpret``/``dtype`` override the kernel defaults (compiled on a
+    TPU, interpreted elsewhere; dtype defaults to float32 — see
     docs/kernels.md for the tolerance policy).
     """
 
@@ -1459,29 +1459,17 @@ SCREENING_BACKENDS: dict[str, ScreeningBackendFactory] = {
 SCREENING_BACKENDS["numpy"] = SCREENING_BACKENDS["batched"]
 
 
-def pallas_is_compiled() -> bool:
-    """True when jax will *compile* Pallas kernels (non-CPU backend).
-
-    On this container's CPU jax, Pallas runs in interpret mode — correct
-    but slow, so auto-selection prefers the vectorized numpy backend there
-    and only tests/CI opt into ``pallas`` explicitly.
-    """
-    try:
-        import jax
-
-        return jax.default_backend() != "cpu"
-    except Exception:  # pragma: no cover - jax always present here
-        return False
-
-
 def select_backend(name: str | None = None) -> ScreeningBackendFactory:
     """Resolve a screening backend by name.
 
-    ``None``/``"auto"`` auto-detects: Pallas where jax compiles it (GPU/TPU),
-    the vectorized numpy ``batched`` backend everywhere else.
+    ``None``/``"auto"`` auto-detects: Pallas where jax compiles it (a TPU —
+    :func:`repro.kernels.pallas_compiled`), the vectorized numpy
+    ``batched`` backend everywhere else.
     """
     if name is None or name == "auto":
-        return SCREENING_BACKENDS["pallas" if pallas_is_compiled() else "batched"]
+        from repro.kernels import pallas_compiled
+
+        return SCREENING_BACKENDS["pallas" if pallas_compiled() else "batched"]
     try:
         return SCREENING_BACKENDS[name]
     except KeyError:

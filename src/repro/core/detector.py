@@ -924,8 +924,8 @@ class FleetDetect:
 
         Cohort batches are encoded as ``None`` (warming), a standalone
         backend snapshot, or the index of their group inside the fused
-        frontier; backends without snapshot support (scalar fan-out,
-        pallas) raise so callers can fall back to fresh execution.
+        frontier; backends without snapshot support (the scalar fan-out)
+        raise so callers can fall back to fresh execution.
         """
         cohorts: list[dict] = []
         group_index: dict[int, int] = {}

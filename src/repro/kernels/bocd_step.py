@@ -41,26 +41,28 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:  # TPU memory spaces; absent members are fine on the interpret path.
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-    _SMEM = pltpu.SMEM
-except Exception:  # pragma: no cover - non-TPU pallas builds
-    pltpu = None
-    _VMEM = _SMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.bocd import DEFAULT_CP_THRESHOLD, _logsumexp_cols
+from repro.kernels import pallas_compiled
 
 #: default frontier when the caller passes ``max_hypotheses=None`` — the
 #: fixed-slot kernel needs *some* static K (uncapped growth is a
 #: numpy-backend feature; 64 comfortably covers the fleet screen's caps).
 DEFAULT_SLOTS = 64
 
+#: Largest padded K·B (slots x streams, see :func:`padded_slot_streams`)
+#: whose whole float32 state fits one kernel's VMEM on a TPU v5e: K=32
+#: compiles at B = 8,192 and runs out of VMEM at B = 12,288; K=64
+#: compiles at B = 4,096 and fails at B = 8,192 (compile-only for
+#: ``v5e:2x2``, tests/test_tpu_compile.py).
+MAX_SLOT_STREAMS = 32 * 8192
 
-def _interpret_default() -> bool:
-    return jax.default_backend() == "cpu"
+
+def padded_slot_streams(k: int, b: int) -> int:
+    """K·B as VMEM holds it: a float32 ``(K, B)`` array is laid out in
+    (8, 128) tiles, so K rounds up to a multiple of 8 and B to one of 128."""
+    return -(-k // 8) * 8 * (-(-b // 128) * 128)
 
 
 def _fused_step(
@@ -188,7 +190,7 @@ def bocd_step(
     ``(log_r, mu, beta, kappa, alpha, rl, p0)``.
     """
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = not pallas_compiled()
     dt = log_r.dtype
     k_slots, b = log_r.shape
     x, mu0, tconst, log_h, log_1mh, log_trunc, cp_const = _prep(
@@ -199,10 +201,8 @@ def bocd_step(
         jnp.asarray(kappa0, dt), jnp.asarray(alpha0, dt),
         jnp.asarray(beta0, dt), cp_const, jnp.zeros((), dt),
     ]).reshape(1, 8)
-    vec = pl.BlockSpec(memory_space=_VMEM) if _VMEM is not None \
-        else pl.BlockSpec()
-    smem = pl.BlockSpec(memory_space=_SMEM) if _SMEM is not None \
-        else pl.BlockSpec()
+    vec = pl.BlockSpec(memory_space=pltpu.VMEM)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pl.pallas_call(
         _step_kernel,
         out_shape=(
@@ -239,6 +239,17 @@ def bocd_step_reference(
     )
 
 
+def _check_fits(k: int, b: int) -> None:
+    padded = padded_slot_streams(k, b)
+    if padded > MAX_SLOT_STREAMS:
+        raise ValueError(
+            f"PallasBOCD state of {k} slots x {b} streams ({padded} once "
+            f"padded to (8, 128) tiles) exceeds the VMEM limit of "
+            f"MAX_SLOT_STREAMS = {MAX_SLOT_STREAMS}; split the streams "
+            "across instances"
+        )
+
+
 class PallasBOCD:
     """Fixed-slot batched BOCD screening backend driven by the fused kernel.
 
@@ -251,10 +262,18 @@ class PallasBOCD:
     ``dtype`` defaults to float32 (the accelerator-native width — see
     docs/kernels.md for the documented tolerance vs the float64 numpy
     oracle); pass ``jnp.float64`` with jax x64 enabled for tight-parity
-    testing. ``interpret`` defaults to auto (True on CPU jax). The whole
-    (K, B) state must fit in VMEM on a compiled backend: at the default 32
-    slots and float32 that bounds B at roughly 30k streams per instance —
-    shard wider fleets across instances (cohorts already do).
+    testing. ``interpret`` defaults to auto (compiled on a TPU, interpreted
+    elsewhere). The kernel keeps the whole (K, B) state in VMEM, so K·B,
+    padded to (8, 128) tiles, may not exceed :data:`MAX_SLOT_STREAMS`: at
+    the default 32 slots that is 8,192 streams per instance — shard wider
+    fleets across instances (cohorts already do). A larger frontier raises
+    ``ValueError`` here and in :meth:`retune`, on every platform, rather
+    than at the TPU compile.
+
+    :meth:`snapshot` / :meth:`restore` carry the state for the campaign
+    engine's forks. jax arrays are immutable and every step rebinds them,
+    so a snapshot holds the arrays themselves: no device copy, and any
+    number of restores may share one.
     """
 
     def __init__(
@@ -276,6 +295,7 @@ class PallasBOCD:
         k = DEFAULT_SLOTS if max_hypotheses is None else int(max_hypotheses)
         if k < 2:
             raise ValueError("PallasBOCD needs at least 2 hypothesis slots")
+        _check_fits(k, b)
         self.n_series = b
         self.hazard = float(hazard)
         self.kappa0 = float(kappa0)
@@ -354,6 +374,7 @@ class PallasBOCD:
         # smallest run length / slot, like the per-tick victim rule), pad
         # with dead slots when growing.
         k_new = int(max_hypotheses)
+        _check_fits(k_new, self.n_series)
         lr = np.asarray(self._log_r, dtype=np.float64)
         k, b = lr.shape
         if k_new < k:
@@ -391,3 +412,18 @@ class PallasBOCD:
                 [self._rl, jnp.zeros((pad, 1), jnp.int32)]
             )
         self.max_hypotheses = k_new
+
+    # -- state capture (campaign fork/restore contract) ------------------
+    _STATE = ("_mu0", "_log_r", "_mu", "_beta", "_kappa", "_alpha", "_rl",
+              "_t", "n_series", "hazard", "max_hypotheses")
+
+    def snapshot(self) -> dict:
+        """The full mutable state (arrays shared, not copied: see the
+        class docstring)."""
+        return {name: getattr(self, name) for name in self._STATE}
+
+    def restore(self, snap: dict) -> None:
+        """Reinstate a :meth:`snapshot` bit-exactly. The instance must have
+        been built with the same dtype and priors."""
+        for name in self._STATE:
+            setattr(self, name, snap[name])

@@ -20,8 +20,8 @@ the reference by construction; versus the float64 numpy oracle the float32
 kernel carries the documented ~1e-5 relative tolerance.
 
 The kernel requires a full hybrid shape (tp > 1, dp > 1, pp > 1);
-degenerate axes stay on the numpy path (the ``PallasReduction`` backend
-falls back automatically).
+:func:`repro.cluster.simulator.resolve_reduction_backend` keeps
+degenerate topologies on the numpy path, as a reported choice.
 """
 from __future__ import annotations
 
@@ -30,19 +30,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU memory spaces; absent members are fine on the interpret path.
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-    _SMEM = pltpu.SMEM
-except Exception:  # pragma: no cover - non-TPU pallas builds
-    pltpu = None
-    _VMEM = _SMEM = None
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() == "cpu"
+from repro.kernels import pallas_compiled
 
 
 def _fused_reduce(cell_speed, tp_edge, dp_edge, hop_bw, alloc_off,
@@ -95,7 +85,7 @@ def cell_reduce(
     don't recompile). Returns ``(t, stage_max, tp_bw, dp_bw)``.
     """
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = not pallas_compiled()
     dt = cell_speed.dtype
     pp, dp, tp = tp_edge.shape
     alloc_off = alloc_off.astype(dt).reshape(1, dp)
@@ -104,10 +94,8 @@ def cell_reduce(
         jnp.asarray(c_tp, dt), jnp.asarray(pp_vol, dt),
         jnp.asarray(c_dp, dt), jnp.zeros((), dt),
     ]).reshape(1, 6)
-    vec = pl.BlockSpec(memory_space=_VMEM) if _VMEM is not None \
-        else pl.BlockSpec()
-    smem = pl.BlockSpec(memory_space=_SMEM) if _SMEM is not None \
-        else pl.BlockSpec()
+    vec = pl.BlockSpec(memory_space=pltpu.VMEM)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pl.pallas_call(
         _reduce_kernel,
         out_shape=(

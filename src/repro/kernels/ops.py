@@ -1,8 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode — the kernel
-body runs in Python per grid step, which validates the exact TPU program
-logic; on a real TPU backend the same calls compile to Mosaic.
+Off a TPU the kernels execute in interpret mode — the kernel body runs in
+Python per grid step, which validates the exact TPU program logic; on a
+TPU backend the same calls compile to Mosaic
+(:func:`repro.kernels.pallas_compiled` decides).
 """
 from __future__ import annotations
 
@@ -11,11 +12,8 @@ import functools
 import jax
 
 from repro.kernels import flash_attention as _fa
+from repro.kernels import pallas_compiled
 from repro.kernels import ssd_scan as _ssd
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() == "cpu"
 
 
 @functools.partial(
@@ -25,7 +23,7 @@ def flash_attention(
     q, k, v, *, causal=True, window=0, block_q=128, block_k=128, interpret=None
 ):
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = not pallas_compiled()
     return _fa.flash_attention(
         q, k, v,
         causal=causal, window=window,
@@ -36,7 +34,7 @@ def flash_attention(
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan(x, dt, a, b_mat, c_mat, *, chunk=128, interpret=None):
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = not pallas_compiled()
     return _ssd.ssd_scan(x, dt, a, b_mat, c_mat, chunk=chunk, interpret=interpret)
 
 
@@ -45,7 +43,7 @@ def flash_decode(q, k, v, valid_len, *, block_k=512, interpret=None):
     from repro.kernels import flash_decode as _fd
 
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = not pallas_compiled()
     return _fd.flash_decode(
         q, k, v, valid_len, block_k=block_k, interpret=interpret
     )

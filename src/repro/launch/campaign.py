@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import argparse
 
+from repro.launch import init_compile_cache
 from repro.scenarios import get_preset, list_presets, run_and_score, write_report
 from repro.scenarios.scoring import RESULTS_DIR
 
@@ -113,6 +114,7 @@ def main() -> None:
     ap.add_argument("--list-presets", action="store_true")
     ap.add_argument("--quiet", action="store_true")
     args = ap.parse_args()
+    init_compile_cache()
 
     if args.list_presets:
         for name in list_presets():

@@ -27,8 +27,8 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
-from repro import compat  # noqa: E402
 from repro.configs.base import INPUT_SHAPES, ArchConfig, get_config, list_archs  # noqa: E402
+from repro.launch import init_compile_cache  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.models import model as model_lib  # noqa: E402
 from repro.models import transformer  # noqa: E402
@@ -198,10 +198,8 @@ def dryrun(arch: str, shape_name: str, multi_pod: bool) -> dict:
     mesh = make_production_mesh(multi_pod=multi_pod)
     t0 = time.monotonic()
     # jax.set_mesh (not the bare `with mesh:`) so the abstract mesh is
-    # visible at trace time — the expert-parallel MoE path reads it. On
-    # older jax the compat shim enters the Mesh context instead, which is
-    # what compat.ambient_mesh() reads there.
-    with compat.set_mesh(mesh):
+    # visible at trace time — the expert-parallel MoE path reads it.
+    with jax.set_mesh(mesh):
         jitted, args = lower_one(cfg, shape_name, mesh)
         lowered = jitted.lower(*args)
         t_lower = time.monotonic() - t0
@@ -246,6 +244,7 @@ def main() -> None:
         help="disable beyond-paper sharding optimizations (EXPERIMENTS §Perf)",
     )
     args = ap.parse_args()
+    init_compile_cache()
     if args.baseline_sharding:
         global SEQ_SHARD_CACHES
         SEQ_SHARD_CACHES = False

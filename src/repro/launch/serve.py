@@ -25,6 +25,7 @@ from repro.cluster.simulator import JobSpec, TrainingSimulator
 from repro.cluster.spec import ClusterSpec, ModelSpec
 from repro.configs.base import get_config
 from repro.core.detector import FalconDetect
+from repro.launch import init_compile_cache
 from repro.launch.train import parse_injection
 from repro.models import model as model_lib, transformer
 from repro.serve.serve_step import make_decode_step, make_prefill_step
@@ -41,6 +42,7 @@ def main() -> None:
     ap.add_argument("--inject", action="append", default=[])
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    init_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

@@ -14,6 +14,9 @@ Seeds are independent campaigns, so ``--workers N`` fans them out over N
 processes; the default stays serial (one process, deterministic resource
 use) and the table is byte-identical either way — each seed's report is a
 pure function of (preset, jobs, seed, ticks), whichever process runs it.
+The pool is CPU-only: on an accelerator each worker would reach for the
+chip the parent (or another worker) already holds, so ``--workers > 1``
+is refused there.
 
 ``--gate`` turns the sweep into a CI regression gate: the aggregate is
 compared against a committed baseline JSON and the process exits non-zero
@@ -29,6 +32,7 @@ import math
 import os
 import sys
 
+from repro.launch import init_compile_cache
 from repro.scenarios import run_and_score
 
 RESULTS_DIR = os.path.join("results", "sweeps")
@@ -125,6 +129,23 @@ def _score_one(task: tuple) -> dict:
     return report
 
 
+def pool_refusal(workers: int) -> str | None:
+    """Why ``workers`` processes may not share this host's jax backend, or
+    None when they may (one worker, or the CPU backend)."""
+    if workers <= 1:
+        return None
+    import jax
+
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return None
+    return (
+        f"--workers {workers} refused on the {backend!r} backend: each "
+        "worker process would claim the accelerator this process holds; "
+        "run the seeds serially (--workers 1)"
+    )
+
+
 def run_sweep(
     preset: str,
     n_jobs: int | None = None,
@@ -140,6 +161,9 @@ def run_sweep(
     """
     tasks = [(preset, n_jobs, seed, max_ticks) for seed in range(seeds)]
     if workers > 1 and seeds > 1:
+        refusal = pool_refusal(workers)
+        if refusal:
+            raise ValueError(refusal)
         import multiprocessing as mp
 
         with mp.get_context("spawn").Pool(min(workers, seeds)) as pool:
@@ -275,6 +299,10 @@ def main(argv: list[str] | None = None) -> int:
                     help="metric a written baseline gates on")
     ap.add_argument("--quiet", action="store_true")
     args = ap.parse_args(argv)
+    refusal = pool_refusal(args.workers)
+    if refusal:
+        ap.error(refusal)
+    init_compile_cache()
 
     sweep = run_sweep(
         args.preset, n_jobs=args.jobs, seeds=args.seeds,

@@ -5,7 +5,8 @@
         [--inject gpu:3:0.5:100:600] [--smoke] [--events]
 
 ``--inject kind:target:severity:start:duration`` adds a fail-slow to the
-attached cluster performance model (kind: gpu|cpu|link|nic). Detection and
+attached cluster performance model (kind: gpu|cpu|link|nic), a simulated
+TP=2 x DP=``--dp-groups`` x PP=2 job (:func:`build_trainer`). Detection and
 mitigation run through :mod:`repro.controlplane`; ``--events`` dumps the
 control plane's typed event log after the run as JSON lines through the
 same :func:`~repro.controlplane.event_log_records` serializer the
@@ -16,13 +17,15 @@ from __future__ import annotations
 
 import argparse
 import json
+from collections.abc import Sequence
 
 from repro.cluster.injector import FailSlowInjector, Injection, InjectionKind
-from repro.controlplane import event_log_records
 from repro.cluster.simulator import JobSpec, TrainingSimulator
 from repro.cluster.spec import ClusterSpec, ModelSpec
-from repro.configs.base import get_config
+from repro.configs.base import ArchConfig, get_config
+from repro.controlplane import event_log_records
 from repro.data.pipeline import DataConfig
+from repro.launch import init_compile_cache
 from repro.optim.adamw import AdamWConfig
 from repro.train.trainer import FalconTrainer
 
@@ -46,6 +49,53 @@ def parse_injection(text: str) -> Injection:
     )
 
 
+def build_trainer(
+    cfg: ArchConfig,
+    data: DataConfig,
+    *,
+    steps: int,
+    injections: Sequence[Injection] = (),
+    falcon_enabled: bool = True,
+    sim_nodes: int | None = None,
+    **trainer_kwargs,
+) -> FalconTrainer:
+    """A :class:`FalconTrainer` wired to its simulated cluster.
+
+    The cluster model is a full hybrid TP=2 x DP x PP=2 job, with DP tied
+    to ``data.dp_groups`` (one DP group per slot column) and one
+    micro-batch per slot and group, on ``sim_nodes`` 4-GPU nodes (default:
+    just enough for the job); ``injections`` are its fail-slows.
+    ``trainer_kwargs`` pass through to the trainer (e.g. ``ckpt_dir``).
+    """
+    tp, pp = 2, 2
+    if sim_nodes is None:
+        sim_nodes = -(-tp * data.dp_groups * pp // 4)
+    sim = TrainingSimulator(
+        cluster=ClusterSpec(n_nodes=sim_nodes, gpus_per_node=4),
+        job=JobSpec(
+            model=ModelSpec(
+                layers=cfg.num_layers,
+                hidden=max(cfg.d_model, 1024),
+                seq_len=data.seq_len,
+                vocab=cfg.vocab_size,
+            ),
+            tp=tp,
+            dp=data.dp_groups,
+            pp=pp,
+            micro_batches=data.slots * data.dp_groups,
+        ),
+    )
+    return FalconTrainer(
+        cfg=cfg,
+        data=data,
+        opt_cfg=AdamWConfig(total_steps=steps),
+        perf_model=sim,
+        injector=FailSlowInjector(list(injections)),
+        falcon_enabled=falcon_enabled,
+        **trainer_kwargs,
+    )
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="falcon-demo-100m")
@@ -57,7 +107,9 @@ def main() -> None:
     ap.add_argument("--dp-groups", type=int, default=4)
     ap.add_argument("--no-falcon", action="store_true")
     ap.add_argument("--inject", action="append", default=[])
-    ap.add_argument("--sim-nodes", type=int, default=2)
+    ap.add_argument("--sim-nodes", type=int, default=None,
+                    help="4-GPU nodes of the simulated cluster "
+                         "(default: just enough for the job)")
     ap.add_argument(
         "--events", action="store_true",
         help="dump the control plane's typed event log after the run "
@@ -68,6 +120,7 @@ def main() -> None:
         help="with --events, keep every Nth per-job Observation (0 = none)",
     )
     args = ap.parse_args()
+    init_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:
@@ -79,31 +132,12 @@ def main() -> None:
         dp_groups=args.dp_groups,
     )
 
-    sim = TrainingSimulator(
-        cluster=ClusterSpec(n_nodes=args.sim_nodes, gpus_per_node=4),
-        job=JobSpec(
-            model=ModelSpec(
-                layers=cfg.num_layers,
-                hidden=max(cfg.d_model, 1024),
-                seq_len=args.seq_len,
-                vocab=cfg.vocab_size,
-            ),
-            tp=2,
-            dp=args.dp_groups,
-            pp=1,
-            micro_batches=args.slots * args.dp_groups,
-        ),
+    trainer = build_trainer(
+        cfg, data, steps=args.steps,
+        injections=[parse_injection(t) for t in args.inject],
+        falcon_enabled=not args.no_falcon, sim_nodes=args.sim_nodes,
     )
-    injector = FailSlowInjector([parse_injection(t) for t in args.inject])
-
-    trainer = FalconTrainer(
-        cfg=cfg,
-        data=data,
-        opt_cfg=AdamWConfig(total_steps=args.steps),
-        perf_model=sim,
-        injector=injector,
-        falcon_enabled=not args.no_falcon,
-    )
+    print(f"# simulated cluster reductions: {trainer.perf_model.reduction_name}")
     history = trainer.run(args.steps)
     print("step,loss,iter_time,wall_time,strategy")
     for r in history:

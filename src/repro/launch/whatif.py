@@ -31,6 +31,7 @@ import json
 import os
 import sys
 
+from repro.launch import init_compile_cache
 from repro.whatif import (
     DecisionRef,
     Variant,
@@ -208,6 +209,7 @@ def main(argv: list[str] | None = None) -> int:
                     help="override the artifact path/dir")
     ap.add_argument("--quiet", action="store_true")
     args = ap.parse_args(argv)
+    init_compile_cache()
 
     report = None
     if args.report:

@@ -16,7 +16,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs.base import ArchConfig
 from repro.models import layers
 from repro.models.schema import ParamDef, Schema
@@ -124,15 +123,9 @@ def _ep_axes(cfg: ArchConfig):
 
     Expert parallelism needs E % model == 0; the GSPMD fallback handles the
     rest. On meshless hosts (CPU smoke tests) the mesh is empty -> None.
-    Older jax (no top-level ``jax.shard_map``) miscompiles this manual
-    pattern in the SPMD partitioner — fall back to GSPMD there too.
     """
-    if not compat.HAS_MODERN_SHARD_MAP:
-        return None
-    mesh = compat.ambient_mesh()
-    if mesh is None:
-        return None
-    names = getattr(mesh, "axis_names", ()) or ()
+    mesh = jax.sharding.get_abstract_mesh()
+    names = mesh.axis_names
     if "model" not in names:
         return None
     tp = mesh.shape["model"]
@@ -208,12 +201,13 @@ def apply_moe(params: dict, x: jax.Array, cfg: ArchConfig) -> tuple[jax.Array, j
         return y.astype(h.dtype).reshape(bl, sl, d), aux
 
     manual = frozenset(("model", *ba))
-    shmapped = compat.shard_map_compat(
+    shmapped = jax.shard_map(
         ep_body,
         mesh=mesh,
         in_specs=(wspec, bspec),
         out_specs=(bspec, P()),
         axis_names=manual,
+        check_vma=False,
     )
     y, aux = shmapped(
         {k: params[k] for k in wspec}, hn

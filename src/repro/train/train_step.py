@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ArchConfig
 from repro.models import model as model_lib
 from repro.optim import adamw
@@ -136,12 +135,13 @@ def make_adaptive_train_step(
         lambda _: P(), model_lib.param_shapes(cfg)
     )  # params replicated over DP axes (model axis stays auto)
 
-    sharded_grad = compat.shard_map_compat(
+    sharded_grad = jax.shard_map(
         grad_fn,
         mesh=mesh,
         in_specs=(param_specs0, bspecs, P(ba)),
         out_specs=(param_specs0, P()),
         axis_names=frozenset(ba),
+        check_vma=False,
     )
 
     def train_step(params, opt_state, batch, counts):

@@ -1,12 +1,14 @@
 """Training loop with FALCON integrated as a first-class runtime feature.
 
 The trainer executes *real* JAX training steps (params genuinely update) and
-feeds FALCON an iteration-time signal. On real hardware that signal is the
-measured step time; on this CPU container, fail-slows are modeled by an
+feeds FALCON an iteration-time signal. On a real cluster that signal would be
+the measured step time (recorded as ``StepRecord.measured``, not yet fed to
+the detector); here fail-slows are modeled by an
 attached :class:`TrainingSimulator` + :class:`FailSlowInjector` (the same
 cluster performance model used in the paper-reproduction benchmarks), so
 detection and mitigation operate on honest dynamics while the numerics stay
-real. DESIGN.md §2 documents this split.
+real. docs/control_plane.md ("Clients") describes how the trainer drives
+the control plane.
 
 Detection and mitigation run through the control plane
 (:mod:`repro.controlplane`): the trainer registers its performance model as
@@ -23,6 +25,8 @@ deprecation shim over the registry.
 """
 from __future__ import annotations
 
+import os
+import tempfile
 import time
 import warnings
 from dataclasses import dataclass, field
@@ -53,6 +57,9 @@ class StepRecord:
     iter_time: float
     wall_time: float
     strategy: str | None = None
+    #: host-clock seconds of the JAX step itself, from dispatch until the
+    #: loss is on the host (the first step includes its compilation)
+    measured: float = 0.0
 
 
 @dataclass
@@ -65,7 +72,9 @@ class FalconTrainer:
     injector: FailSlowInjector | None = None
     falcon_enabled: bool = True
     overheads: dict = field(default_factory=lambda: dict(DEFAULT_OVERHEADS))
-    ckpt_dir: str = "/tmp/repro_ckpt"
+    ckpt_dir: str = field(
+        default_factory=lambda: os.path.join(tempfile.gettempdir(), "repro_ckpt")
+    )
     seed: int = 0
 
     params: dict = field(init=False)
@@ -95,8 +104,11 @@ class FalconTrainer:
                 injector=self.injector,
             )
             self.detector = self._job.detector
+        # Donate params and optimizer state: the step's outputs reuse their
+        # buffers, so the device holds one copy of each, not two.
         self._step_fn = jax.jit(
-            ts_lib.make_train_step(self.cfg, self.opt_cfg)
+            ts_lib.make_train_step(self.cfg, self.opt_cfg),
+            donate_argnums=(0, 1),
         )
 
     @property
@@ -194,6 +206,7 @@ class FalconTrainer:
                     iter_time=iter_time,
                     wall_time=self._wall,
                     strategy=strategy_applied,
+                    measured=measured,
                 )
             )
         return self.history

@@ -193,3 +193,17 @@ def test_parallel_sweep_is_byte_identical_to_serial(tmp_path):
     p2 = sweep_mod.write_sweep(fanned, str(tmp_path / "fanned"))
     with open(p1, "rb") as f1, open(p2, "rb") as f2:
         assert f1.read() == f2.read()
+
+
+def test_sweep_pool_refused_off_cpu(monkeypatch):
+    """One process per chip: on an accelerator backend a worker pool is
+    refused before any campaign runs; on the CPU it stays available."""
+    import jax
+
+    assert sweep_mod.pool_refusal(4) is None  # CPU backend
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert sweep_mod.pool_refusal(1) is None
+    assert "'tpu'" in sweep_mod.pool_refusal(2)
+    monkeypatch.setattr(sweep_mod, "run_and_score", None)  # never reached
+    with pytest.raises(ValueError, match="--workers 2 refused"):
+        sweep_mod.run_sweep("single_gpu_throttle", seeds=2, workers=2)

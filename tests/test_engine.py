@@ -266,3 +266,28 @@ def test_scored_report_identical_from_engine_runs():
     runs_fresh = {m: run_campaign(spec, m) for m in MODES}
     runs_eng = {m: engine.run(m) for m in MODES}
     assert score_campaign(spec, runs_fresh) == score_campaign(spec, runs_eng)
+
+
+# ------------------------------------------------ the default path on a TPU
+def test_engine_forks_the_pallas_screen(monkeypatch):
+    """Where the platform is a TPU, ``"auto"`` picks the Pallas screen and
+    ``run_and_score``'s default path still runs on the engine: its
+    snapshots carry ``PallasBOCD`` state, and every mode equals a fresh
+    run on the same backends. The platform decision is patched to report
+    a TPU; the kernels, which read the real platform, stay in interpret
+    mode on the CPU."""
+    import repro.kernels
+    from repro.core import bocd
+    from repro.kernels import bocd_step as bk
+
+    monkeypatch.setattr(repro.kernels, "pallas_compiled", lambda: True)
+    assert bocd.select_backend("auto").name == "pallas"
+    snaps = []
+    take = bk.PallasBOCD.snapshot
+    monkeypatch.setattr(
+        bk.PallasBOCD, "snapshot", lambda self: snaps.append(1) or take(self)
+    )
+    spec = build_campaign("single_gpu_throttle", seed=0, max_ticks=150)
+    engine = assert_engine_matches_fresh(spec)
+    assert snaps
+    assert engine.stats["forked_runs"] + engine.stats["reused_runs"] >= 2

@@ -18,6 +18,7 @@ from repro.cluster.spec import ClusterSpec, ModelSpec
 from repro.core import bocd
 from repro.core.detector import FalconDetect, FleetDetect
 from repro.core.ringbuf import MatrixRingBuffer, RingBuffer
+from repro.kernels import pallas_compiled
 
 MODEL = ModelSpec(layers=24, hidden=4096, seq_len=2048, vocab=50257)
 
@@ -623,7 +624,7 @@ def test_screening_backend_registry_resolution():
     assert bocd.select_backend("batched").name == "batched"
     assert bocd.select_backend("numpy").name == "batched"  # alias
     auto = bocd.select_backend(None)
-    assert auto.name == ("pallas" if bocd.pallas_is_compiled() else "batched")
+    assert auto.name == ("pallas" if pallas_compiled() else "batched")
     with pytest.raises(ValueError, match="unknown screening backend"):
         bocd.select_backend("fpga")
     # factory instances pass through; backend classes warn but still work
@@ -677,19 +678,20 @@ def test_reduction_backends_equivalent():
 def test_reduction_backend_resolution_and_sim_knob():
     from repro.cluster import simulator as S
 
+    sim = _faulted_sim(seed=3)
+    job = sim.job
     # the hot path stays inline for the defaults (no indirection object)
-    assert S.resolve_reduction_backend(None) is None or \
-        S.resolve_reduction_backend(None).name == "pallas"
-    assert S.resolve_reduction_backend("vectorized") is None
-    assert S.resolve_reduction_backend("numpy") is None
-    assert S.resolve_reduction_backend("reference").name == "reference"
+    auto = S.resolve_reduction_backend(None, job)
+    assert auto is None if not pallas_compiled() else auto.name == "pallas"
+    assert S.resolve_reduction_backend("vectorized", job) is None
+    assert S.resolve_reduction_backend("numpy", job) is None
+    assert S.resolve_reduction_backend("reference", job).name == "reference"
     with pytest.raises(ValueError, match="unknown reduction backend"):
         S.select_reduction_backend("abacus")
     with pytest.raises(TypeError):
-        S.resolve_reduction_backend(42)
+        S.resolve_reduction_backend(42, job)
 
     # the TrainingSimulator knob swaps backends and stays consistent
-    sim = _faulted_sim(seed=3)
     t_vec = sim.iteration_time()
     sim.reduction = "reference"
     t_ref = sim.iteration_time()
@@ -704,6 +706,34 @@ def test_reduction_backend_resolution_and_sim_knob():
     np.testing.assert_allclose(
         t_after, sim.iteration_time_reference(), rtol=1e-4
     )
+
+
+def test_reduction_backend_degenerate_topology_is_reported(monkeypatch):
+    """A job with a parallel axis of 1 runs the inline path even where
+    Pallas compiles, says so, and refuses an explicit pallas backend."""
+    import jax
+
+    from repro.cluster import simulator as S
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = ModelSpec(layers=8, hidden=1024, seq_len=512, vocab=32000)
+    flat = TrainingSimulator(
+        cluster=ClusterSpec(n_nodes=2, gpus_per_node=4),
+        job=JobSpec(model=model, tp=2, dp=4, pp=1, micro_batches=16),
+    )
+    assert not S.fits_pallas_reduction(flat.job)
+    assert S.resolve_reduction_backend("auto", flat.job) is None
+    assert flat.reduction_name == "vectorized"
+    with pytest.raises(ValueError, match="pp=1"):
+        S.resolve_reduction_backend("pallas", flat.job)
+    with pytest.raises(ValueError, match="pp=1"):
+        S.resolve_reduction_backend(S.PallasReduction(), flat.job)
+    hybrid = _faulted_sim()
+    assert S.fits_pallas_reduction(hybrid.job)
+    assert hybrid.reduction_name == "pallas"
+    # reassigning the job re-resolves the backend
+    hybrid.job = flat.job
+    assert hybrid.reduction_name == "vectorized"
 
 
 # ------------------------------------------- fused multi-cohort screen

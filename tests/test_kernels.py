@@ -207,6 +207,58 @@ from repro.kernels import bocd_step as bk  # noqa: E402
 from repro.kernels import cell_reduce as ck  # noqa: E402
 
 
+@pytest.mark.parametrize(
+    "k,b", [(32, 8193), (64, 4097), (8, 32769), (66, 3600), (3, 32769)]
+)
+def test_pallas_bocd_refuses_state_above_vmem_bound(k, b, monkeypatch):
+    """K·B above the measured v5e VMEM bound raises before any array is
+    built or any kernel compiles, naming the limit. The bound counts the
+    (8, 128) tile padding: 66 x 3,600 is under it as a plain product but
+    pads to 72 x 3,712, and 3 slots pad to 8."""
+    assert bk.padded_slot_streams(k, b) > bk.MAX_SLOT_STREAMS
+    monkeypatch.setattr(bk, "bocd_step", None)  # must never be reached
+    monkeypatch.setattr(bk.jnp, "asarray", None)
+    with pytest.raises(ValueError, match="MAX_SLOT_STREAMS = 262144"):
+        bk.PallasBOCD(b, max_hypotheses=k)
+
+
+def test_pallas_bocd_accepts_state_at_vmem_bound():
+    det = bk.PallasBOCD(8192, max_hypotheses=32)
+    assert det.max_hypotheses * det.n_series == bk.MAX_SLOT_STREAMS
+    with pytest.raises(ValueError, match="MAX_SLOT_STREAMS"):
+        det.retune(max_hypotheses=33)  # growing the frontier is checked too
+    assert det.max_hypotheses == 32
+
+
+def test_pallas_bocd_snapshot_restore_is_bit_exact():
+    """A restored instance (built fresh, as FleetDetect.restore builds
+    it) continues exactly like the one that was never interrupted, and
+    the same snapshot seeds a second fork after the first ran on."""
+    b = 6
+    rng = np.random.default_rng(5)
+    x = rng.normal(1.0, 0.05, (20, b))
+    x[12:] *= 1.3
+    ref = bk.PallasBOCD(b, mu0=x[0], max_hypotheses=8, interpret=True)
+    for t in range(10):
+        ref.update(x[t])
+    ref.retune(hazard=0.02, max_hypotheses=6)
+    snap = ref.snapshot()
+    want = [ref.update(x[t]) for t in range(10, 20)]
+    for _ in range(2):
+        fork = bk.PallasBOCD(b, max_hypotheses=8, interpret=True)
+        fork.restore(snap)
+        got = [fork.update(x[t]) for t in range(10, 20)]
+        for g, w in zip(got, want, strict=True):
+            np.testing.assert_array_equal(g, w)
+        np.testing.assert_array_equal(
+            fork.p_recent_change(), ref.p_recent_change()
+        )
+        np.testing.assert_array_equal(
+            fork.map_runlength(), ref.map_runlength()
+        )
+        assert (fork.max_hypotheses, fork.hazard) == (6, 0.02)
+
+
 def _bocd_state(k, b, seed=0, dtype=jnp.float32):
     det = bk.PallasBOCD(b, max_hypotheses=k, dtype=dtype, interpret=True)
     return det

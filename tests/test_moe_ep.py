@@ -35,12 +35,10 @@ x = jax.random.normal(jax.random.key(1), (4, 16, cfg.d_model), jnp.float32).asty
 # (a) dense-host path (no ambient mesh).
 y_ref, aux_ref = jax.jit(lambda p, h: moe.apply_moe(p, h, cfg))(params, x)
 
-# (b) expert-parallel path under the mesh, entered through the same
-# version shim the product code uses.
-from repro import compat
-
+# (b) expert-parallel path under the mesh, entered with jax.set_mesh as
+# the product code does.
 mesh = jax.make_mesh((2, 2), ("data", "model"))
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     y_ep, aux_ep = jax.jit(lambda p, h: moe.apply_moe(p, h, cfg))(params, x)
 
 np.testing.assert_allclose(
@@ -52,7 +50,7 @@ np.testing.assert_allclose(float(aux_ref), float(aux_ep), rtol=1e-2, atol=1e-2)
 # model-only manual axes and still agree.
 x1 = x[:1, :1]
 y1_ref, _ = jax.jit(lambda p, h: moe.apply_moe(p, h, cfg))(params, x1)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     y1_ep, _ = jax.jit(lambda p, h: moe.apply_moe(p, h, cfg))(params, x1)
 np.testing.assert_allclose(
     np.asarray(y1_ref, np.float32), np.asarray(y1_ep, np.float32),

@@ -50,6 +50,29 @@ def test_loss_decreases_over_training():
     assert last < first - 0.3, (first, last)
 
 
+def test_trainer_step_donates_params_and_opt_state(tmp_path):
+    """The step's outputs reuse the params + AdamW buffers, so a device
+    holds one copy of each: the compile aliases every argument byte but
+    the batch's, and a step consumes the old params."""
+    cfg = tiny_cfg()
+    data = DataConfig(seq_len=32, global_batch=4, slots=2, dp_groups=2)
+    trainer = FalconTrainer(
+        cfg=cfg, data=data, opt_cfg=AdamWConfig(total_steps=2),
+        perf_model=None, falcon_enabled=False, ckpt_dir=str(tmp_path),
+    )
+    batch = make_batch(cfg, data, 0)
+    mem = trainer._step_fn.lower(
+        trainer.params, trainer.opt_state, batch
+    ).compile().memory_analysis()
+    state_bytes = sum(
+        x.nbytes for x in jax.tree.leaves((trainer.params, trainer.opt_state))
+    )
+    assert mem.alias_size_in_bytes == state_bytes
+    old = jax.tree.leaves(trainer.params)[0]
+    trainer.run(1)
+    assert old.is_deleted()
+
+
 @pytest.mark.slow
 @pytest.mark.slow
 def test_falcon_detects_and_mitigates_injected_failslow():
@@ -122,14 +145,6 @@ def test_adaptive_train_step_multidevice():
     import os
     import subprocess
     import sys
-
-    from repro import compat
-
-    if not compat.HAS_MODERN_SHARD_MAP:
-        pytest.skip(
-            "partial-manual shard_map hard-aborts in this jax's XLA "
-            "(hlo_sharding_util IsManualSubgroup check; see ROADMAP)"
-        )
 
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
