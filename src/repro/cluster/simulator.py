@@ -4,8 +4,7 @@ Models one training job under (TP, DP, PP) hybrid parallelism on a
 :class:`ClusterState`, with 1F1B pipelining, ring collectives, per-DP-group
 micro-batch counts (S2), and a logical->physical placement permutation (S3).
 It implements the :class:`repro.core.detector.ClusterInterface` protocol so
-FALCON-DETECT runs against it unchanged, and emits the same CommEvent
-stream the Monitor shim would log on a real job.
+FALCON-DETECT runs against it unchanged.
 
 The model intentionally follows the paper's own cost reasoning
 (Appendix 9.2): compute time = FLOPs / effective speed; collective time =
@@ -53,7 +52,6 @@ from typing import Protocol, runtime_checkable
 
 import numpy as np
 
-from repro.core.events import CommEvent, CommOp
 from repro.core.topology import HybridTopology
 from repro.cluster.spec import ClusterSpec, ClusterState, DirtySet, ModelSpec
 from repro.obs.collectives import CollectiveBreakdown, decompose, timing_decomposition
@@ -1123,17 +1121,6 @@ class TrainingSimulator:
                     del vdict[k]
             for k, v in saved.items():
                 vdict[k] = v  # no-ops (and stays clean) when already equal
-
-    # ---------------------------------------------- monitor event stream
-    ITER_PATTERN = (CommOp.REDUCE_SCATTER, CommOp.ALL_GATHER, CommOp.ALL_REDUCE)
-
-    def emit_events(self, t_start: float, iter_time: float, rank: int = 0) -> list[CommEvent]:
-        """CommEvents one real iteration would leave in the Monitor log."""
-        k = len(self.ITER_PATTERN)
-        return [
-            CommEvent(op=op, timestamp=t_start + iter_time * (i / k), rank=rank)
-            for i, op in enumerate(self.ITER_PATTERN)
-        ]
 
     # --------------------------------------- dirty-cursor adapter surface
     def state_cursor(self) -> tuple[int, int]:

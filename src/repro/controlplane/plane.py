@@ -57,6 +57,7 @@ from repro.controlplane.strategies import (
     StrategyRegistry,
     default_registry,
 )
+from repro.obs.host import span
 
 
 @dataclass(frozen=True)
@@ -446,22 +447,26 @@ class ControlPlane:
         :class:`MitigationResult.overhead` to the job's wall clock.
         """
         job = self._jobs[job_id]
-        out: list[ControlEvent] = [
-            Observation(
-                job_id=job_id, time=now, iter_time=iter_time, step=job.steps
+        with span("controlplane.observe", step=job.steps):
+            out: list[ControlEvent] = [
+                Observation(
+                    job_id=job_id, time=now, iter_time=iter_time,
+                    step=job.steps,
+                )
+            ]
+            job.steps += 1
+            self._watched_s += max(iter_time, 0.0)
+            self.watchdog.beat(job_id, now)
+            job._last_sample = iter_time
+            job._last_seen = now
+            job._alarmed = False
+            had_active = job.detector.active_event is not None
+            new_event = job.detector.observe(iter_time, now)
+            out += self._after_detection(
+                job, new_event, had_active, iter_time, now
             )
-        ]
-        job.steps += 1
-        self._watched_s += max(iter_time, 0.0)
-        self.watchdog.beat(job_id, now)
-        job._last_sample = iter_time
-        job._last_seen = now
-        job._alarmed = False
-        had_active = job.detector.active_event is not None
-        new_event = job.detector.observe(iter_time, now)
-        out += self._after_detection(job, new_event, had_active, iter_time, now)
-        self.events += out
-        return out
+            self.events += out
+            return out
 
     # -- fleet screening path ------------------------------------------
     def tick(
@@ -481,6 +486,14 @@ class ControlPlane:
         the normal pinpoint path, yielding a hang-flagged Diagnosis and a
         hang mitigation ladder.
         """
+        tick = self._fleet._ticks if self._fleet is not None else 0
+        with span("controlplane.tick", tick=tick):
+            return self._tick(times, now)
+
+    def _tick(
+        self, times: Mapping[str, float] | Sequence[float] | np.ndarray,
+        now: float,
+    ) -> list[ControlEvent]:
         jobs = list(self._jobs.values())
         tr = self.tracer
         if tr is not None:

@@ -32,6 +32,7 @@ import numpy as np
 from repro.core import bocd, validation
 from repro.core.events import ChangePoint, FailSlowEvent, RootCause
 from repro.core.ringbuf import MatrixRingBuffer, RingBuffer
+from repro.obs.host import span
 
 VERIFY_THRESHOLD = 0.10  # <10 % before/after difference => jitter (§4.2)
 SUSPICIOUS_FACTOR = 1.1  # >1.1x median transfer time => suspicious (§4.3)
@@ -689,6 +690,16 @@ class FleetDetect:
     ``FleetDetect`` fed that window; ``max_cohorts`` triggers it
     automatically so per-tick cost stays one batched update per cohort,
     bounded.
+
+    Each tick's host phases are wall-clock spans (:mod:`repro.obs.host`):
+    ``fleet.tick`` around the call, and inside it ``fleet.ewma``,
+    ``fleet.warm``, ``fleet.bocd_update``, ``fleet.posterior``,
+    ``fleet.flags``, ``fleet.drift``, ``fleet.consolidate`` and
+    ``fleet.retune``, each carrying the tick number. ``verify_attempts``
+    counts candidates sent to the exact verification and
+    ``verify_confirmed`` those it confirmed; ``fleet.tick`` carries both
+    as of the tick's start, so a trace shows how far they moved. Neither
+    is part of :meth:`snapshot`.
     """
 
     n_workers: int
@@ -783,6 +794,8 @@ class FleetDetect:
         self._flags_total = 0
         self._worker_ticks = 0
         self._ticks = 0
+        self.verify_attempts = 0
+        self.verify_confirmed = 0
         # The ring must retain every window any screen reads: the widest
         # verification scale and the drift screen's reference lookback — a
         # smaller user-set history_cap would silently blind those paths.
@@ -1027,6 +1040,13 @@ class FleetDetect:
     # ------------------------------------------------------------------
     def tick(self, times: np.ndarray) -> list[FleetFlag]:
         """Feed one iteration time per worker; returns verified flags."""
+        tick = self._ticks
+        with span("fleet.tick", tick=tick,
+                  verify_attempts=self.verify_attempts,
+                  verify_confirmed=self.verify_confirmed):
+            return self._tick(times, tick)
+
+    def _tick(self, times: np.ndarray, tick: int) -> list[FleetFlag]:
         times = np.asarray(times, dtype=np.float64)
         if times.shape != (self.n_workers,):
             raise ValueError(
@@ -1036,14 +1056,15 @@ class FleetDetect:
         n = len(self._history)
         i = n - 1
         if self.ewma_span:
-            # Long-horizon baseline: slow EWMA per stream, seeded on the
-            # first sample, re-anchored on every confirmed flag.
-            fresh = np.isnan(self._ewma)
-            if fresh.any():
-                self._ewma[fresh] = times[fresh]
-            alpha = 2.0 / (self.ewma_span + 1.0)
-            self._ewma += alpha * (times - self._ewma)
-            self._ewma_age += 1
+            with span("fleet.ewma", tick=tick):
+                # Long-horizon baseline: slow EWMA per stream, seeded on
+                # the first sample, re-anchored on every confirmed flag.
+                fresh = np.isnan(self._ewma)
+                if fresh.any():
+                    self._ewma[fresh] = times[fresh]
+                alpha = 2.0 / (self.ewma_span + 1.0)
+                self._ewma += alpha * (times - self._ewma)
+                self._ewma_age += 1
         out: list[FleetFlag] = []
         if self._fused:
             # Fused pre-pass: warm any ready cohorts into the shared
@@ -1062,8 +1083,10 @@ class FleetDetect:
                         x[cohort.batch.cols] = (
                             times[cols] / self._scale[cols]
                         )
-                self._multi.update(x)
-        drift_ref_mean, drift_cur_mean = self._drift_means(n)
+                with span("fleet.bocd_update", tick=tick):
+                    self._multi.update(x)
+        with span("fleet.drift", tick=tick):
+            drift_ref_mean, drift_cur_mean = self._drift_means(n)
         for cohort in self._cohorts:
             cols = cohort.cols_array()
             if cohort.batch is None:
@@ -1071,65 +1094,79 @@ class FleetDetect:
                     continue
                 cohort.batch = self._warm_cohort(cohort, n)
             if not self._fused:
-                cohort.batch.update(times[cols] / self._scale[cols])
+                with span("fleet.bocd_update", tick=tick):
+                    cohort.batch.update(times[cols] / self._scale[cols])
             if i - cohort.start <= self.recent_window:
                 continue
-            p = cohort.batch.p_recent_change(self.recent_window)
-            flagged = np.flatnonzero(p > self.cp_threshold)
+            with span("fleet.posterior", tick=tick):
+                p = cohort.batch.p_recent_change(self.recent_window)
+                flagged = np.flatnonzero(p > self.cp_threshold)
             if flagged.size:
-                run_lengths = cohort.batch.map_runlength()
-                for local_w in flagged:
-                    w = cohort.cols[int(local_w)]
-                    idx = i - int(run_lengths[local_w])
-                    if (
-                        idx <= cohort.start
-                        or idx - self._last_flag[w] < self.min_gap
-                    ):
-                        continue
-                    cp = self._verify(w, idx, n, floor=cohort.start)
-                    if cp is not None:
-                        # Dedup on *confirmed* flags only: the first
-                        # post-onset ticks may lack the 2 after-samples
-                        # verification needs, and the detection burst must
-                        # be allowed to retry until one sticks.
-                        self._last_flag[w] = idx
-                        self._anchor(w, cp.mean_after)
-                        out.append(FleetFlag(worker=w, change_point=cp))
-            out += self._drift_screen(
-                cohort, cols, n, drift_ref_mean, drift_cur_mean
-            )
-        out += self._long_drift_screen(n, drift_cur_mean)
+                with span("fleet.flags", tick=tick):
+                    out += self._bocd_flags(cohort, flagged, i, n)
+            with span("fleet.drift", tick=tick):
+                out += self._drift_screen(
+                    cohort, cols, n, drift_ref_mean, drift_cur_mean
+                )
+        with span("fleet.drift", tick=tick):
+            out += self._long_drift_screen(n, drift_cur_mean)
         if (
             self.max_cohorts is not None
             and sum(1 for c in self._cohorts if c.batch is not None)
             > self.max_cohorts
         ):
-            self.consolidate()
+            with span("fleet.consolidate", tick=tick):
+                self.consolidate()
         self._flags_total += len(out)
         self._worker_ticks += self.n_workers
         self._ticks += 1
         if self.adapt_every and self._ticks % self.adapt_every == 0:
-            self._retune()
+            with span("fleet.retune", tick=tick):
+                self._retune()
+        return out
+
+    def _bocd_flags(
+        self, cohort: _Cohort, flagged: np.ndarray, i: int, n: int
+    ) -> list[FleetFlag]:
+        """Verify the change each stream of ``flagged`` (cohort-local
+        indices over the threshold) has at its MAP run length."""
+        out: list[FleetFlag] = []
+        run_lengths = cohort.batch.map_runlength()
+        for local_w in flagged:
+            w = cohort.cols[int(local_w)]
+            idx = i - int(run_lengths[local_w])
+            if idx <= cohort.start or idx - self._last_flag[w] < self.min_gap:
+                continue
+            cp = self._verify(w, idx, n, floor=cohort.start)
+            if cp is not None:
+                # Dedup on *confirmed* flags only: the first post-onset
+                # ticks may lack the 2 after-samples verification needs,
+                # and the detection burst must be allowed to retry until
+                # one sticks.
+                self._last_flag[w] = idx
+                self._anchor(w, cp.mean_after)
+                out.append(FleetFlag(worker=w, change_point=cp))
         return out
 
     def _warm_cohort(self, cohort: _Cohort, n: int) -> bocd.ScreeningBackend:
         """Warm one cohort: estimate noise scales from its retained window,
         build a standalone batch, and replay every row but the current one
         (the caller feeds that through the per-tick update path)."""
-        cols = np.asarray(cohort.cols, dtype=np.int64)
-        warm = self._history.rows(cohort.start, n)[:, cols]
-        scale = bocd.noise_scale_batch(warm)
-        self._scale[cols] = scale
-        batch = self._backend.make(
-            cols.size,
-            hazard=self.hazard,
-            mu0=warm[0] / scale,
-            cp_threshold=self.cp_threshold,
-            max_hypotheses=self.max_hypotheses,
-        )
-        for row in warm[:-1]:
-            batch.update(row / scale)
-        return batch
+        with span("fleet.warm", tick=self._ticks):
+            cols = np.asarray(cohort.cols, dtype=np.int64)
+            warm = self._history.rows(cohort.start, n)[:, cols]
+            scale = bocd.noise_scale_batch(warm)
+            self._scale[cols] = scale
+            batch = self._backend.make(
+                cols.size,
+                hazard=self.hazard,
+                mu0=warm[0] / scale,
+                cp_threshold=self.cp_threshold,
+                max_hypotheses=self.max_hypotheses,
+            )
+            for row in warm[:-1]:
+                batch.update(row / scale)
+            return batch
 
     def _drift_means(
         self, n: int
@@ -1316,6 +1353,7 @@ class FleetDetect:
     def _verify(
         self, worker: int, idx: int, n: int, floor: int = 0
     ) -> ChangePoint | None:
+        self.verify_attempts += 1
         for w in (self.verify_window, *self.verify_windows):
             lo = max(floor, idx - w, self._history.start)
             hi = min(n, idx + w)
@@ -1326,6 +1364,7 @@ class FleetDetect:
                 self.verify_threshold,
             )
             if cp is not None:
+                self.verify_confirmed += 1
                 return cp
         return None
 

@@ -36,6 +36,7 @@ construction — the parity tests assert exact equality.
 from __future__ import annotations
 
 import functools
+import weakref
 
 import jax
 import jax.numpy as jnp
@@ -45,6 +46,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.bocd import DEFAULT_CP_THRESHOLD, _logsumexp_cols
 from repro.kernels import pallas_compiled
+from repro.obs.host import span
 
 #: default frontier when the caller passes ``max_hypotheses=None`` — the
 #: fixed-slot kernel needs *some* static K (uncapped growth is a
@@ -274,7 +276,20 @@ class PallasBOCD:
     engine's forks. jax arrays are immutable and every step rebinds them,
     so a snapshot holds the arrays themselves: no device copy, and any
     number of restores may share one.
+
+    Host-device traffic is counted where it happens: ``h2d_bytes`` (host
+    arrays copied to the device, and the step's five scalar arguments,
+    which cross as Python floats on every call), ``d2h_bytes`` and
+    ``host_reads`` (blocking reads of device arrays). A state array read
+    again unchanged is served from the host copy JAX keeps, and counts
+    once. Each copy is a wall-clock span, ``bocd.upload`` or
+    ``bocd.readback`` (:mod:`repro.obs.host`), carrying the step number;
+    a read-back also carries the ``bytes`` it adds to ``d2h_bytes``, so a
+    trace shows the counter.
     """
+
+    #: scalar arguments of :func:`bocd_step` passed as Python floats
+    _SCALAR_ARGS = 5
 
     def __init__(
         self,
@@ -306,13 +321,22 @@ class PallasBOCD:
         self.max_hypotheses = k
         self.dtype = jnp.dtype(dtype)
         self.interpret = interpret
+        self._t = 0
+        self.h2d_bytes = 0
+        self.d2h_bytes = 0
+        self.host_reads = 0
+        #: state name -> the array of it last read (see :meth:`_read`)
+        self._read_last: dict[str, weakref.ref] = {}
+        # A Python float crosses at JAX's default float width.
+        self._scalar_bytes = self._SCALAR_ARGS * jnp.dtype(
+            jax.dtypes.canonicalize_dtype(np.float64)).itemsize
         mu0 = np.broadcast_to(np.asarray(mu0, dtype=np.float64), (b,))
-        self._mu0 = jnp.asarray(mu0, self.dtype)
+        self._mu0 = self._put(mu0, self.dtype)
         # Slot 0 holds the prior hypothesis; slots 1..K-1 start dead
         # (-inf mass) and are recycled as the frontier fills.
         log_r = np.full((k, b), -np.inf)
         log_r[0] = 0.0
-        self._log_r = jnp.asarray(log_r, self.dtype)
+        self._log_r = self._put(log_r, self.dtype)
         self._mu = jnp.broadcast_to(self._mu0[None, :], (k, b)).astype(
             self.dtype
         )
@@ -320,41 +344,67 @@ class PallasBOCD:
         self._kappa = jnp.full((k, 1), kappa0, self.dtype)
         self._alpha = jnp.full((k, 1), alpha0, self.dtype)
         self._rl = jnp.zeros((k, 1), jnp.int32)
-        self._t = 0
+
+    # -- host-device copies ----------------------------------------------
+    def _put(self, host: np.ndarray, dtype=None) -> jax.Array:
+        """``host`` copied to the device."""
+        with span("bocd.upload", step=self._t):
+            dev = jnp.asarray(host, dtype)
+        self.h2d_bytes += dev.nbytes
+        return dev
+
+    def _read(self, name: str | None, dev: jax.Array) -> np.ndarray:
+        """``dev`` read to the host, blocking. ``name`` names the state
+        array ``dev`` is; reading the same array of it again costs no copy
+        (JAX keeps the host copy) and is not counted."""
+        last = self._read_last.get(name) if name else None
+        crosses = last is None or last() is not dev
+        nbytes = dev.nbytes if crosses else 0
+        with span("bocd.readback", step=self._t, bytes=nbytes):
+            host = np.asarray(dev)
+        if crosses:
+            self.d2h_bytes += nbytes
+            self.host_reads += 1
+            if name:
+                self._read_last[name] = weakref.ref(dev)
+        return host
 
     # -- ScreeningBackend interface ------------------------------------
     @property
     def n_hypotheses(self) -> int:
-        return int(np.isfinite(np.asarray(self._log_r)).any(axis=1).sum())
+        lr = self._read("log_r", self._log_r)
+        return int(np.isfinite(lr).any(axis=1).sum())
 
     def update(self, x: np.ndarray) -> np.ndarray:
         x = np.asarray(x, dtype=np.float64)
         if x.shape != (self.n_series,):
             raise ValueError(f"expected shape ({self.n_series},), got {x.shape}")
+        x_dev = self._put(x, self.dtype)
+        self.h2d_bytes += self._scalar_bytes
         (self._log_r, self._mu, self._beta, self._kappa, self._alpha,
          self._rl, p0) = bocd_step(
-            jnp.asarray(x, self.dtype), self._log_r, self._mu, self._beta,
+            x_dev, self._log_r, self._mu, self._beta,
             self._kappa, self._alpha, self._rl, self._mu0,
             self.hazard, self.kappa0, self.alpha0, self.beta0,
             self.truncation, interpret=self.interpret,
         )
         self._t += 1
-        return np.asarray(p0[0], dtype=np.float64)
+        return np.asarray(self._read(None, p0[0]), dtype=np.float64)
 
     def p_recent_change(self, window: int = 2) -> np.ndarray:
-        lr = np.asarray(self._log_r, dtype=np.float64)
-        recent = np.asarray(self._rl)[:, 0] <= window
+        lr = np.asarray(self._read("log_r", self._log_r), dtype=np.float64)
+        recent = self._read("rl", self._rl)[:, 0] <= window
         if not recent.any():
             return np.zeros(self.n_series)
         return np.exp(_logsumexp_cols(lr[recent]))
 
     def map_runlength(self) -> np.ndarray:
-        lr = np.asarray(self._log_r)
-        rl = np.asarray(self._rl)[:, 0].astype(np.int64)
+        lr = self._read("log_r", self._log_r)
+        rl = self._read("rl", self._rl)[:, 0].astype(np.int64)
         return rl[np.argmax(lr, axis=0)]
 
     def take_columns(self, idx: np.ndarray) -> None:
-        idx = jnp.asarray(np.asarray(idx, dtype=np.int64))
+        idx = self._put(np.asarray(idx, dtype=np.int64))
         self.n_series = int(idx.size)
         self._mu0 = self._mu0[idx]
         self._log_r = self._log_r[:, idx]
@@ -375,16 +425,16 @@ class PallasBOCD:
         # with dead slots when growing.
         k_new = int(max_hypotheses)
         _check_fits(k_new, self.n_series)
-        lr = np.asarray(self._log_r, dtype=np.float64)
+        lr = np.asarray(self._read("log_r", self._log_r), dtype=np.float64)
         k, b = lr.shape
         if k_new < k:
             strength = np.where(
                 np.isnan(lr).any(axis=1), -np.inf, np.max(lr, axis=1)
             )
-            rl = np.asarray(self._rl)[:, 0]
+            rl = self._read("rl", self._rl)[:, 0]
             order = np.lexsort((np.arange(k), -rl, -strength))
             keep = np.sort(order[:k_new])
-            sel = jnp.asarray(keep)
+            sel = self._put(keep)
             self._log_r = self._log_r[sel]
             self._mu = self._mu[sel]
             self._beta = self._beta[sel]

@@ -1,6 +1,6 @@
 """Fleet observability layer — tracing, decomposition, metrics, dashboards.
 
-Four pieces (docs/observability.md):
+Five pieces (docs/observability.md):
 
 * :mod:`repro.obs.tracer` — :class:`SpanTracer`, a simulated-clock span
   recorder exported as Chrome trace-event JSON (``<name>.trace.json``,
@@ -9,6 +9,10 @@ Four pieces (docs/observability.md):
   :func:`~repro.scenarios.campaign.run_campaign` to see tick cadence,
   watchdog silence windows, executor attempt/retry cycles, and per-job
   fault episodes as nested spans.
+* :mod:`repro.obs.host` — :func:`~repro.obs.host.span` /
+  :func:`~repro.obs.host.step`: wall-clock spans on the ``jax.profiler``
+  timeline, beside the device ops, recorded only while a profiler session
+  is active. Imported explicitly (``from repro.obs import host``).
 * :mod:`repro.obs.collectives` — :class:`CollectiveBreakdown` +
   :func:`decompose`: an iteration's critical path split into
   compute / TP-allreduce / PP-p2p / DP-allreduce with the bottleneck

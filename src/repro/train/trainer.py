@@ -22,6 +22,12 @@ an in-memory checkpoint restore; the runtime analogue of S3 (mesh device
 permutation + state re-put) is exposed as :func:`remap_mesh` for
 multi-device runs. ``FalconTrainer._apply_strategy`` remains as a thin
 deprecation shim over the registry.
+
+Each step is a wall-clock step span (:mod:`repro.obs.host`), ``train.step``,
+holding ``train.batch`` (``make_batch`` and the upload),
+``train.dispatch`` and ``train.loss_sync`` (together what
+``StepRecord.measured`` times), ``train.simulate`` (the performance
+model's iteration time), ``controlplane.observe`` and ``train.mitigate``.
 """
 from __future__ import annotations
 
@@ -41,10 +47,10 @@ from repro.controlplane import ControlPlane, MitigationResult
 from repro.controlplane.strategies import MitigationContext
 from repro.core.detector import FalconDetect
 from repro.core.events import Strategy, strategy_label
-from repro.core.monitor import Monitor
 from repro.core.planner import DEFAULT_OVERHEADS
 from repro.data.pipeline import DataConfig, make_batch
 from repro.models import model as model_lib
+from repro.obs.host import span, step as step_span
 from repro.optim import adamw
 from repro.train import train_step as ts_lib
 from repro.train.checkpoint import CheckpointManager
@@ -79,7 +85,6 @@ class FalconTrainer:
 
     params: dict = field(init=False)
     opt_state: adamw.AdamWState = field(init=False)
-    monitor: Monitor = field(init=False)
     control: ControlPlane | None = field(init=False, default=None)
     detector: FalconDetect | None = field(init=False, default=None)
     history: list[StepRecord] = field(init=False, default_factory=list)
@@ -89,9 +94,6 @@ class FalconTrainer:
     def __post_init__(self) -> None:
         self.params = model_lib.init_params(self.cfg, self.seed)
         self.opt_state = adamw.init(self.params)
-        # The monitor logs on the trainer's simulated wall clock, so comm
-        # events and control-plane events share one timebase.
-        self.monitor = Monitor(clock=lambda: self._wall)
         self.ckpt = CheckpointManager(self.ckpt_dir)
         self.allocation = [self.data.slots] * self.data.dp_groups
         if self.perf_model is not None:
@@ -151,65 +153,69 @@ class FalconTrainer:
 
     def _mirror_result(self, ev: MitigationResult) -> None:
         """Reflect a strategy's modeled effects into the JAX-side state."""
-        counts = ev.detail.get("allocation")
-        if counts is not None and len(counts) == self.data.dp_groups:
-            self.allocation = list(counts)
-        if ev.strategy is Strategy.CKPT_AND_RESTART and ev.applied:
-            # In-memory checkpoint restore (fast path, Fig. 19 'M'); the
-            # modeled side (simulator restart + injection relief) already
-            # ran inside CkptRestartStrategy.
-            self.ckpt.save_memory(self.params)
-            self.params = self.ckpt.restore_memory()
-            self.allocation = [self.data.slots] * self.data.dp_groups
+        with span("train.mitigate", step=len(self.history)):
+            counts = ev.detail.get("allocation")
+            if counts is not None and len(counts) == self.data.dp_groups:
+                self.allocation = list(counts)
+            if ev.strategy is Strategy.CKPT_AND_RESTART and ev.applied:
+                # In-memory checkpoint restore (fast path, Fig. 19 'M'); the
+                # modeled side (simulator restart + injection relief) already
+                # ran inside CkptRestartStrategy.
+                self.ckpt.save_memory(self.params)
+                self.params = self.ckpt.restore_memory()
+                self.allocation = [self.data.slots] * self.data.dp_groups
 
     # ------------------------------------------------------------------
     def run(self, num_steps: int) -> list[StepRecord]:
         for step in range(num_steps):
+            # Spans carry the trainer's step count, which runs on across
+            # calls (``step`` restarts at 0 in each).
+            k = len(self.history)
+            with step_span("train.step", k):
+                self.history.append(self._step(step, k))
+        return self.history
+
+    def _step(self, step: int, k: int) -> StepRecord:
+        with span("train.batch", step=k):
             batch = jax.tree.map(
                 jnp.asarray, make_batch(self.cfg, self.data, step)
             )
-            t0 = time.monotonic()
+        t0 = time.monotonic()
+        with span("train.dispatch", step=k):
             self.params, self.opt_state, metrics = self._step_fn(
                 self.params, self.opt_state, batch
             )
+        with span("train.loss_sync", step=k):
             loss = float(metrics["loss"])
-            measured = time.monotonic() - t0
+        measured = time.monotonic() - t0
 
+        with span("train.simulate", step=k):
             iter_time = self._observed_iter_time(measured, self._wall)
-            self._wall += iter_time
-            for ev in (
-                self.perf_model.emit_events(self._wall - iter_time, iter_time)
-                if self.perf_model
-                else []
-            ):
-                self.monitor.extend([ev])
+        self._wall += iter_time
 
-            strategy_applied: str | None = None
-            if self.falcon_enabled and self.control is not None:
-                for ev in self.control.observe("train", iter_time, self._wall):
-                    if not isinstance(ev, MitigationResult):
-                        continue
-                    if ev.kind == "relief":
-                        # Relief: re-balance micro-batches for the recovered
-                        # cluster (S2 with a healthy profile = even split).
-                        self._mirror_result(ev)
-                        strategy_applied = "REBALANCE"
-                    else:
-                        self._mirror_result(ev)
-                        self._wall += ev.overhead
-                        strategy_applied = strategy_label(ev.strategy)
+        strategy_applied: str | None = None
+        if self.falcon_enabled and self.control is not None:
+            for ev in self.control.observe("train", iter_time, self._wall):
+                if not isinstance(ev, MitigationResult):
+                    continue
+                if ev.kind == "relief":
+                    # Relief: re-balance micro-batches for the recovered
+                    # cluster (S2 with a healthy profile = even split).
+                    self._mirror_result(ev)
+                    strategy_applied = "REBALANCE"
+                else:
+                    self._mirror_result(ev)
+                    self._wall += ev.overhead
+                    strategy_applied = strategy_label(ev.strategy)
 
-            self.history.append(
-                StepRecord(
-                    step=step,
-                    loss=loss,
-                    iter_time=iter_time,
-                    wall_time=self._wall,
-                    strategy=strategy_applied,
-                    measured=measured,
-                )
-            )
-        return self.history
+        return StepRecord(
+            step=step,
+            loss=loss,
+            iter_time=iter_time,
+            wall_time=self._wall,
+            strategy=strategy_applied,
+            measured=measured,
+        )
 
 
 # ---------------------------------------------------------------- S3 util
