@@ -73,14 +73,20 @@ def log(phase: str, msg: str) -> None:
 
 #: the jitted kernel wrappers whose compiled shapes the kernel check replays
 KERNELS = ("bocd_step", "cell_reduce")
+#: jitted function -> (the kernel it launches, the argument whose shape is
+#: recorded); ``_advance`` is ``PallasBOCD``'s per-tick launch of
+#: ``bocd_step``'s kernel
+LAUNCHES = {"jit(bocd_step)": ("bocd_step", 1), "jit(_advance)": ("bocd_step", 2),
+            "jit(cell_reduce)": ("cell_reduce", 1)}
 
 
 class CompileLog(logging.Handler):
     """Counts backend compiles, their seconds, and persistent-cache hits
     through ``jax.monitoring`` (listeners stay registered; read deltas),
-    and records the shape of every ``bocd_step`` / ``cell_reduce`` compile
-    (argument 1: the ``(K, B)`` state or the ``(pp, dp, tp)`` edges) from
-    the per-compile record jax's ``pxla`` logger writes at DEBUG."""
+    and records the shape of every compile that launches ``bocd_step`` /
+    ``cell_reduce`` (:data:`LAUNCHES`: the ``(K, B)`` state or the
+    ``(pp, dp, tp)`` edges) from the per-compile record jax's ``pxla``
+    logger writes at DEBUG."""
 
     def __init__(self) -> None:
         super().__init__(logging.DEBUG)
@@ -99,9 +105,9 @@ class CompileLog(logging.Handler):
         if not record.msg.startswith("Compiling %s with global shapes"):
             return
         name, avals = record.args[0], record.args[1]
-        for k in KERNELS:
-            if name == f"jit({k})":
-                self.kernel_shapes[k].add(tuple(avals[1].shape))
+        if name in LAUNCHES:
+            kernel, arg = LAUNCHES[name]
+            self.kernel_shapes[kernel].add(tuple(avals[arg].shape))
 
     def _duration(self, event: str, secs: float, **_) -> None:
         if event == "/jax/core/compile/backend_compile_duration":

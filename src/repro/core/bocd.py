@@ -1307,6 +1307,12 @@ class ScreeningBackend(Protocol):
     ``Pr(r_t = 0)`` per stream; ``p_recent_change``/``map_runlength`` report
     posterior statistics; ``take_columns`` sub-slices streams on membership
     churn; ``retune`` adjusts hazard / frontier cap for future updates.
+
+    A device backend (``PallasBOCD``) copies up only ``x`` per tick and
+    returns ``update``'s ``p0`` row as a device array, unread: a caller
+    that wants it on the host calls ``np.asarray`` (``FleetDetect``
+    discards it). Its statistics are reduced on the device, in the state's
+    dtype, and only their (B,) answer is read back.
     """
 
     n_series: int

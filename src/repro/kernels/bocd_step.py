@@ -44,7 +44,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.bocd import DEFAULT_CP_THRESHOLD, _logsumexp_cols
+from repro.core.bocd import DEFAULT_CP_THRESHOLD
 from repro.kernels import pallas_compiled
 from repro.obs.host import span
 
@@ -161,48 +161,45 @@ def _step_kernel(
         ref[:] = val
 
 
-def _prep(x, log_r, alpha, mu0, hazard, alpha0, truncation):
-    """Shared launch prologue: scalar params + the gammaln constants the
-    kernel can't compute (Mosaic has no lgamma)."""
-    dt = log_r.dtype
+def _tconst(alpha, dt):
+    """The Student-t predictive's gammaln constant per slot, computed
+    outside the kernel (Mosaic has no lgamma)."""
     gammaln = jax.scipy.special.gammaln
     df = 2.0 * alpha.astype(dt)
-    tconst = gammaln((df + 1.0) / 2.0) - gammaln(df / 2.0)
+    return gammaln((df + 1.0) / 2.0) - gammaln(df / 2.0)
+
+
+def _priors(hazard, alpha0, truncation, dt):
+    """The step's scalars that depend on the priors alone:
+    ``(log_h, log_1mh, log_trunc, cp_const)``."""
+    gammaln = jax.scipy.special.gammaln
     a0 = jnp.asarray(alpha0, dt)
     cp_const = gammaln((2.0 * a0 + 1.0) / 2.0) - gammaln(a0)
     hz = jnp.asarray(hazard, dt)
-    log_h = jnp.log(hz)
-    log_1mh = jnp.log1p(-hz)
     log_trunc = jnp.log(jnp.asarray(truncation, dt))
-    x = x.astype(dt).reshape(1, -1)
-    mu0 = mu0.astype(dt).reshape(1, -1)
-    return x, mu0, tconst, log_h, log_1mh, log_trunc, cp_const
+    return jnp.log(hz), jnp.log1p(-hz), log_trunc, cp_const
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def bocd_step(
-    x, log_r, mu, beta, kappa, alpha, rl, mu0,
-    hazard, kappa0=1.0, alpha0=1.0, beta0=1.0, truncation=1e-6,
-    *, interpret=None,
-):
-    """One fused step as a single ``pallas_call`` launch.
-
-    State dtypes/shapes as in :func:`_fused_step`; ``hazard`` may be a
-    traced scalar (retunes don't recompile). Returns
-    ``(log_r, mu, beta, kappa, alpha, rl, p0)``.
-    """
-    if interpret is None:
-        interpret = not pallas_compiled()
-    dt = log_r.dtype
-    k_slots, b = log_r.shape
-    x, mu0, tconst, log_h, log_1mh, log_trunc, cp_const = _prep(
-        x, log_r, alpha, mu0, hazard, alpha0, truncation
+def _params(hazard, kappa0, alpha0, beta0, truncation, dt):
+    """The kernel's (1, 8) SMEM row: ``log_h, log_1mh, log_trunc, kappa0,
+    alpha0, beta0, cp_const`` and a pad."""
+    log_h, log_1mh, log_trunc, cp_const = _priors(
+        hazard, alpha0, truncation, dt
     )
-    params = jnp.stack([
+    return jnp.stack([
         log_h, log_1mh, log_trunc,
         jnp.asarray(kappa0, dt), jnp.asarray(alpha0, dt),
         jnp.asarray(beta0, dt), cp_const, jnp.zeros((), dt),
     ]).reshape(1, 8)
+
+
+def _launch(params, x, log_r, mu, beta, kappa, alpha, rl, mu0, interpret):
+    """The ``pallas_call`` over the state, given its params row."""
+    dt = log_r.dtype
+    k_slots, b = log_r.shape
+    tconst = _tconst(alpha, dt)
+    x = x.astype(dt).reshape(1, -1)
+    mu0 = mu0.astype(dt).reshape(1, -1)
     vec = pl.BlockSpec(memory_space=pltpu.VMEM)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pl.pallas_call(
@@ -219,7 +216,27 @@ def bocd_step(
         in_specs=[smem] + [vec] * 9,
         out_specs=(vec,) * 7,
         interpret=interpret,
+        name="bocd_step",
     )(params, x, log_r, mu, beta, kappa, alpha, rl, tconst, mu0)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def bocd_step(
+    x, log_r, mu, beta, kappa, alpha, rl, mu0,
+    hazard, kappa0=1.0, alpha0=1.0, beta0=1.0, truncation=1e-6,
+    *, interpret=None,
+):
+    """One fused step as a single ``pallas_call`` launch.
+
+    State dtypes/shapes as in :func:`_fused_step`; ``hazard`` may be a
+    traced scalar (retunes don't recompile). Returns
+    ``(log_r, mu, beta, kappa, alpha, rl, p0)``.
+    """
+    if interpret is None:
+        interpret = not pallas_compiled()
+    params = _params(hazard, kappa0, alpha0, beta0, truncation, log_r.dtype)
+    return _launch(params, x, log_r, mu, beta, kappa, alpha, rl, mu0,
+                   interpret)
 
 
 @jax.jit
@@ -230,15 +247,56 @@ def bocd_step_reference(
     """The kernel's math as a plain traced function (no ``pallas_call``) —
     the bit-match oracle for interpret-mode parity tests."""
     dt = log_r.dtype
-    x, mu0, tconst, log_h, log_1mh, log_trunc, cp_const = _prep(
-        x, log_r, alpha, mu0, hazard, alpha0, truncation
+    log_h, log_1mh, log_trunc, cp_const = _priors(
+        hazard, alpha0, truncation, dt
     )
     return _fused_step(
-        x, log_r, mu, beta, kappa, alpha, rl, tconst, mu0,
+        x.astype(dt).reshape(1, -1), log_r, mu, beta, kappa, alpha, rl,
+        _tconst(alpha, dt), mu0.astype(dt).reshape(1, -1),
         log_h, log_1mh, log_trunc,
         jnp.asarray(kappa0, dt), jnp.asarray(alpha0, dt),
         jnp.asarray(beta0, dt), cp_const,
     )
+
+
+@functools.partial(jax.jit, static_argnames=("dt",))
+def _params_row(priors, dt):
+    """:func:`_params` on the device from the uploaded ``(hazard, kappa0,
+    alpha0, beta0, truncation)``."""
+    return _params(*(priors[i] for i in range(5)), dt)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _advance(params, x, log_r, mu, beta, kappa, alpha, rl, mu0, *,
+             interpret):
+    """:class:`PallasBOCD`'s tick: the launch over device arrays alone,
+    with ``p0`` as a (B,) row."""
+    if interpret is None:
+        interpret = not pallas_compiled()
+    *state, p0 = _launch(params, x, log_r, mu, beta, kappa, alpha, rl, mu0,
+                         interpret)
+    return (*state, p0.reshape(-1))
+
+
+@jax.jit
+def _p_recent(log_r, rl, window):
+    """Pr(run length <= ``window``) per stream, in the state's dtype: the
+    max-shifted logsumexp of the slots with ``rl <= window``,
+    exponentiated. A stream with no live recent slot gives 0."""
+    lr = jnp.where(rl <= window, log_r, -jnp.inf)
+    m = jnp.max(lr, axis=0)
+    shift = jnp.where(jnp.isfinite(m), m, 0.0)
+    return jnp.exp(jnp.log(jnp.sum(jnp.exp(lr - shift), axis=0)) + shift)
+
+
+@jax.jit
+def _map_runlength(log_r, rl):
+    """The run length of each stream's most probable slot; ties go to the
+    first slot, as ``np.argmax`` breaks them. The slot is picked by a
+    masked sum, not a gather, which a TPU runs far slower."""
+    slots = jax.lax.broadcasted_iota(jnp.int32, log_r.shape, 0)
+    best = slots == jnp.argmax(log_r, axis=0)
+    return jnp.sum(jnp.where(best, rl, 0), axis=0)
 
 
 def _check_fits(k: int, b: int) -> None:
@@ -258,8 +316,11 @@ class PallasBOCD:
     Drop-in for :class:`repro.core.bocd.BatchedBOCD` behind the
     ``ScreeningBackend`` interface (``update`` / ``p_recent_change`` /
     ``map_runlength`` / ``take_columns`` / ``retune``). State lives as jax
-    arrays and advances one kernel launch per tick; posterior statistics
-    are read back to numpy on demand.
+    arrays and advances one kernel launch per tick. ``update`` returns the
+    ``p0`` row as a device array, unread: a caller that wants it on the
+    host calls ``np.asarray``. ``p_recent_change`` and ``map_runlength``
+    reduce the state on the device, in the state's dtype, and read back
+    only their (B,) answer.
 
     ``dtype`` defaults to float32 (the accelerator-native width — see
     docs/kernels.md for the documented tolerance vs the float64 numpy
@@ -277,19 +338,20 @@ class PallasBOCD:
     so a snapshot holds the arrays themselves: no device copy, and any
     number of restores may share one.
 
-    Host-device traffic is counted where it happens: ``h2d_bytes`` (host
-    arrays copied to the device, and the step's five scalar arguments,
-    which cross as Python floats on every call), ``d2h_bytes`` and
-    ``host_reads`` (blocking reads of device arrays). A state array read
-    again unchanged is served from the host copy JAX keeps, and counts
-    once. Each copy is a wall-clock span, ``bocd.upload`` or
-    ``bocd.readback`` (:mod:`repro.obs.host`), carrying the step number;
-    a read-back also carries the ``bytes`` it adds to ``d2h_bytes``, so a
-    trace shows the counter.
+    The kernel's params row (the priors and the hazard, as
+    :func:`_params` derives them) is built on the device once and again
+    only on ``retune(hazard=...)``, so in the steady state a tick copies up
+    only ``x``, in the state dtype, and reads nothing back until a
+    statistic is asked for. Host-device traffic is counted where it
+    happens: ``h2d_bytes`` (host arrays copied to the device),
+    ``d2h_bytes`` and ``host_reads`` (the blocking reads the backend
+    itself makes). A statistic asked for again while the state is
+    unchanged is served from the host copy JAX keeps, and counts once.
+    Each copy is a wall-clock span, ``bocd.upload`` or ``bocd.readback``
+    (:mod:`repro.obs.host`), carrying the step number; a read-back also
+    carries the ``bytes`` it adds to ``d2h_bytes``, so a trace shows the
+    counter.
     """
-
-    #: scalar arguments of :func:`bocd_step` passed as Python floats
-    _SCALAR_ARGS = 5
 
     def __init__(
         self,
@@ -325,11 +387,15 @@ class PallasBOCD:
         self.h2d_bytes = 0
         self.d2h_bytes = 0
         self.host_reads = 0
-        #: state name -> the array of it last read (see :meth:`_read`)
+        #: state array or statistic -> the array of it last read (_read)
         self._read_last: dict[str, weakref.ref] = {}
-        # A Python float crosses at JAX's default float width.
-        self._scalar_bytes = self._SCALAR_ARGS * jnp.dtype(
-            jax.dtypes.canonicalize_dtype(np.float64)).itemsize
+        #: statistic -> (log_r, rl, args, its device answer); see :meth:`_stat`
+        self._stats: dict[str, tuple] = {}
+        #: window -> it as a device scalar, copied up once
+        self._windows: dict[int, jax.Array] = {}
+        #: the (K, B) shape whose statistics are compiled (see :meth:`update`)
+        self._warm_shape: tuple | None = None
+        self._params = self._params_for(self.hazard)
         mu0 = np.broadcast_to(np.asarray(mu0, dtype=np.float64), (b,))
         self._mu0 = self._put(mu0, self.dtype)
         # Slot 0 holds the prior hypothesis; slots 1..K-1 start dead
@@ -345,6 +411,13 @@ class PallasBOCD:
         self._alpha = jnp.full((k, 1), alpha0, self.dtype)
         self._rl = jnp.zeros((k, 1), jnp.int32)
 
+    def _params_for(self, hazard: float) -> jax.Array:
+        """The kernel's params row for ``hazard`` and the priors, built on
+        the device."""
+        priors = np.array([hazard, self.kappa0, self.alpha0, self.beta0,
+                           self.truncation])
+        return _params_row(self._put(priors, self.dtype), self.dtype)
+
     # -- host-device copies ----------------------------------------------
     def _put(self, host: np.ndarray, dtype=None) -> jax.Array:
         """``host`` copied to the device."""
@@ -353,11 +426,12 @@ class PallasBOCD:
         self.h2d_bytes += dev.nbytes
         return dev
 
-    def _read(self, name: str | None, dev: jax.Array) -> np.ndarray:
-        """``dev`` read to the host, blocking. ``name`` names the state
-        array ``dev`` is; reading the same array of it again costs no copy
-        (JAX keeps the host copy) and is not counted."""
-        last = self._read_last.get(name) if name else None
+    def _read(self, name: str, dev: jax.Array) -> np.ndarray:
+        """``dev`` read to the host, blocking. ``name`` names what ``dev``
+        holds (a state array or a statistic); reading the same array under
+        it again costs no copy (JAX keeps the host copy) and is not
+        counted."""
+        last = self._read_last.get(name)
         crosses = last is None or last() is not dev
         nbytes = dev.nbytes if crosses else 0
         with span("bocd.readback", step=self._t, bytes=nbytes):
@@ -365,8 +439,7 @@ class PallasBOCD:
         if crosses:
             self.d2h_bytes += nbytes
             self.host_reads += 1
-            if name:
-                self._read_last[name] = weakref.ref(dev)
+            self._read_last[name] = weakref.ref(dev)
         return host
 
     # -- ScreeningBackend interface ------------------------------------
@@ -375,33 +448,55 @@ class PallasBOCD:
         lr = self._read("log_r", self._log_r)
         return int(np.isfinite(lr).any(axis=1).sum())
 
-    def update(self, x: np.ndarray) -> np.ndarray:
-        x = np.asarray(x, dtype=np.float64)
+    def update(self, x: np.ndarray) -> jax.Array:
+        x = np.asarray(x, dtype=self.dtype)
         if x.shape != (self.n_series,):
             raise ValueError(f"expected shape ({self.n_series},), got {x.shape}")
-        x_dev = self._put(x, self.dtype)
-        self.h2d_bytes += self._scalar_bytes
+        x_dev = self._put(x)
         (self._log_r, self._mu, self._beta, self._kappa, self._alpha,
-         self._rl, p0) = bocd_step(
-            x_dev, self._log_r, self._mu, self._beta,
+         self._rl, p0) = _advance(
+            self._params, x_dev, self._log_r, self._mu, self._beta,
             self._kappa, self._alpha, self._rl, self._mu0,
-            self.hazard, self.kappa0, self.alpha0, self.beta0,
-            self.truncation, interpret=self.interpret,
+            interpret=self.interpret,
         )
         self._t += 1
-        return np.asarray(self._read(None, p0[0]), dtype=np.float64)
+        if self._log_r.shape != self._warm_shape:
+            # Compile the statistics at this shape now, so the first tick
+            # that asks for one (a flag may come late) compiles nothing.
+            _p_recent(self._log_r, self._rl, jnp.zeros((), jnp.int32))
+            _map_runlength(self._log_r, self._rl)
+            self._warm_shape = self._log_r.shape
+        return p0
+
+    def _window(self, window: int) -> jax.Array:
+        """``window`` as a device int32 scalar: a Python int passed to the
+        reduction would be copied up on every call."""
+        dev = self._windows.get(window)
+        if dev is None:
+            dev = self._windows[window] = self._put(np.int32(window))
+        return dev
+
+    def _stat(self, name: str, fn, *args) -> np.ndarray:
+        """``fn(log_r, rl, *args)`` computed on the device and read back.
+        Asked again with the same state and ``args`` (device arrays, so
+        compared by identity), the same device answer is read, which
+        costs no copy."""
+        hit = self._stats.get(name)
+        if (hit is None or hit[0]() is not self._log_r
+                or hit[1]() is not self._rl
+                or any(a is not b for a, b in zip(hit[2], args))):
+            dev = fn(self._log_r, self._rl, *args)
+            hit = (weakref.ref(self._log_r), weakref.ref(self._rl), args, dev)
+            self._stats[name] = hit
+        return self._read(name, hit[3])
 
     def p_recent_change(self, window: int = 2) -> np.ndarray:
-        lr = np.asarray(self._read("log_r", self._log_r), dtype=np.float64)
-        recent = self._read("rl", self._rl)[:, 0] <= window
-        if not recent.any():
-            return np.zeros(self.n_series)
-        return np.exp(_logsumexp_cols(lr[recent]))
+        return self._stat("p_recent_change", _p_recent,
+                          self._window(int(window)))
 
     def map_runlength(self) -> np.ndarray:
-        lr = self._read("log_r", self._log_r)
-        rl = self._read("rl", self._rl)[:, 0].astype(np.int64)
-        return rl[np.argmax(lr, axis=0)]
+        rl = self._stat("map_runlength", _map_runlength)
+        return rl.astype(np.int64)
 
     def take_columns(self, idx: np.ndarray) -> None:
         idx = self._put(np.asarray(idx, dtype=np.int64))
@@ -418,6 +513,7 @@ class PallasBOCD:
     ) -> None:
         if hazard is not None:
             self.hazard = float(hazard)
+            self._params = self._params_for(self.hazard)
         if max_hypotheses is None or max_hypotheses == self.max_hypotheses:
             return
         # Resize the slot frontier: keep the strongest rows (ties to the
@@ -465,7 +561,7 @@ class PallasBOCD:
 
     # -- state capture (campaign fork/restore contract) ------------------
     _STATE = ("_mu0", "_log_r", "_mu", "_beta", "_kappa", "_alpha", "_rl",
-              "_t", "n_series", "hazard", "max_hypotheses")
+              "_params", "_t", "n_series", "hazard", "max_hypotheses")
 
     def snapshot(self) -> dict:
         """The full mutable state (arrays shared, not copied: see the

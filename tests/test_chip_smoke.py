@@ -66,6 +66,9 @@ def test_kernel_phase_sees_interpret_mode_off_tpu(smoke):
     cell_reduce(f((2, 2)), f((2, 2, 2)), f((2, 2, 2)), f((1, 2)), f((2,)),
                 1.0, 1.0, 1.0, 1.0, 1.0)
     assert (2, 2, 2) in compiles.kernel_shapes["cell_reduce"]
+    from repro.kernels.bocd_step import PallasBOCD
+    PallasBOCD(3, max_hypotheses=16, interpret=True).update(f(3))
+    assert (16, 3) in compiles.kernel_shapes["bocd_step"]
     got = smoke.kernel_phase(
         {"bocd_step": {(32, 5)}, "cell_reduce": {(2, 2, 2)}}
     )

@@ -228,29 +228,37 @@ def test_control_plane_tick_is_a_span_with_its_tick(tmp_path):
 def test_pallas_backend_counts_the_bytes_that_cross():
     k, b = 8, 128
     be = PallasBOCD(b, max_hypotheses=k, interpret=True)
-    scalars = PallasBOCD._SCALAR_ARGS * 4  # Python floats cross as float32
-    assert be.h2d_bytes == b * 4 + k * b * 4  # mu0 and log_r
+    priors = 5 * 4  # hazard, kappa0, alpha0, beta0, truncation: the params row
+    assert be.h2d_bytes == priors + b * 4 + k * b * 4  # and mu0 and log_r
     assert (be.d2h_bytes, be.host_reads) == (0, 0)
     rng = np.random.default_rng(0)
     for i in range(3):
-        be.update(1.0 + 0.01 * rng.standard_normal(b))
-    assert be.h2d_bytes == b * 4 + k * b * 4 + 3 * (b * 4 + scalars)
-    assert be.d2h_bytes == 3 * b * 4  # each step's p0 row
-    assert be.host_reads == 3
-    state = k * b * 4 + k * 4  # log_r and rl
+        p0 = be.update(1.0 + 0.01 * rng.standard_normal(b))
+    assert be.h2d_bytes == priors + b * 4 + k * b * 4 + 3 * b * 4  # x alone
+    assert (be.d2h_bytes, be.host_reads) == (0, 0)  # p0 stays on the device
+    assert isinstance(p0, jax.Array) and p0.shape == (b,)
+    h2d = be.h2d_bytes
     be.p_recent_change(2)
-    assert be.d2h_bytes == 3 * b * 4 + state and be.host_reads == 5
-    # read again unchanged: served from JAX's host copy, not counted
+    assert be.d2h_bytes == b * 4 and be.host_reads == 1
+    assert be.h2d_bytes == h2d + 4  # the window, once per value
+    be.map_runlength()
+    assert be.d2h_bytes == 2 * b * 4 and be.host_reads == 2
+    # asked again unchanged: served from JAX's host copy, not counted
     be.map_runlength()
     be.p_recent_change(2)
-    _ = be.n_hypotheses
-    assert be.d2h_bytes == 3 * b * 4 + state and be.host_reads == 5
+    assert be.d2h_bytes == 2 * b * 4 and be.host_reads == 2
+    _ = be.n_hypotheses  # not per tick: reads the whole log_r
+    assert be.d2h_bytes == 2 * b * 4 + k * b * 4 and be.host_reads == 3
     be.update(np.ones(b))
     be.map_runlength()
-    assert be.d2h_bytes == 4 * b * 4 + 2 * state and be.host_reads == 8
+    be.p_recent_change(2)
+    assert be.d2h_bytes == 4 * b * 4 + k * b * 4 and be.host_reads == 5
+    assert be.h2d_bytes == h2d + 4 + b * 4
     h2d = be.h2d_bytes
+    be.retune(hazard=0.02)
+    assert be.h2d_bytes == h2d + priors
     be.take_columns(np.arange(b // 2))
-    assert be.h2d_bytes == h2d + (b // 2) * 4  # the int32 index
+    assert be.h2d_bytes == h2d + priors + (b // 2) * 4  # the int32 index
 
 
 def test_spans_are_free_and_import_no_jax_without_jax():

@@ -374,6 +374,148 @@ def test_pallas_bocd_take_columns_equals_fresh_slice():
     )
 
 
+
+# The posterior statistics reduce on the device in the state's dtype; the
+# host path they replaced read the state back and reduced it in float64.
+# Under x64 the two differ only by XLA's float64 exp/log against numpy's
+# (one ulp on some inputs), so that case is held to a few ulp.
+STAT_TOL = {"float32": dict(rtol=0, atol=1e-6),
+            "float64": dict(rtol=1e-14, atol=0)}
+
+
+def _host_p_recent(det, window):
+    """The host path: float64 logsumexp over the read-back slots."""
+    lr = np.asarray(det._log_r, np.float64)
+    recent = np.asarray(det._rl)[:, 0] <= window
+    if not recent.any():
+        return np.zeros(det.n_series)
+    return np.exp(bocd._logsumexp_cols(lr[recent]))
+
+
+def _stats_run(dtype, b=24, k=8, ticks=30):
+    """A detector through a change in every third stream, and the
+    samples it has not seen yet."""
+    rng = np.random.default_rng(6)
+    x = rng.normal(1.0, 0.05, (ticks + 4, b))
+    x[ticks // 2:, ::3] *= 1.3
+    det = bk.PallasBOCD(b, mu0=x[0], max_hypotheses=k, dtype=dtype,
+                        interpret=True)
+    for t in range(ticks):
+        det.update(x[t])
+    return det, x[ticks:]
+
+
+def _plant_edges(det):
+    """Stream 0 loses every slot of run length <= 2; stream 1 ties every
+    slot; stream 2 ties two live slots above the rest; stream 3 is all
+    -inf (np.argmax gives slot 0)."""
+    lr = np.asarray(det._log_r).copy()
+    rl = np.asarray(det._rl)[:, 0]
+    assert (rl <= 2).any() and (rl > 2).any()
+    lr[rl <= 2, 0] = -np.inf
+    lr[:, 1] = np.log(1.0 / lr.shape[0])
+    lr[:, 2] = np.log(0.01)
+    lr[[2, 5], 2] = np.log(0.3)
+    lr[:, 3] = -np.inf
+    det._log_r = jnp.asarray(lr, det.dtype)
+
+
+def _assert_stats_match_host(det, dtype):
+    rl = np.asarray(det._rl)[:, 0]
+    for window in (0, 2, int(rl.max()) + 1):
+        got = det.p_recent_change(window)
+        assert got.dtype == det.dtype and got.shape == (det.n_series,)
+        np.testing.assert_allclose(got, _host_p_recent(det, window),
+                                   **STAT_TOL[dtype])
+    lr = np.asarray(det._log_r)
+    got = det.map_runlength()
+    np.testing.assert_array_equal(got, rl[np.argmax(lr, axis=0)])
+    return got
+
+
+@pytest.mark.parametrize("dtype", ["float32", "float64"])
+@pytest.mark.parametrize("stage", ["run", "take_columns", "retune", "restore"])
+def test_pallas_bocd_statistics_match_the_host_path(stage, dtype):
+    """``p_recent_change`` (windows 0, 2 and past every run length) and
+    ``map_runlength`` reduced on the device equal the host path over the
+    same state, ties and dead streams included, after each way the state
+    can change besides a step."""
+    with jax.enable_x64(dtype == "float64"):
+        det, x = _stats_run(jnp.dtype(dtype))
+        if stage == "take_columns":
+            det.take_columns(np.arange(0, det.n_series, 2))
+            x = x[:, ::2]
+        elif stage == "retune":
+            det.retune(hazard=0.03)
+        elif stage == "restore":
+            snap = det.snapshot()
+            det.retune(hazard=0.03, max_hypotheses=6)
+            det.update(x[0])
+            det.restore(snap)
+        det.update(x[1])
+        _assert_stats_match_host(det, dtype)
+        _plant_edges(det)
+        assert (det.p_recent_change(2)[[0, 3]] == 0).all()
+        rl = np.asarray(det._rl)[:, 0]
+        run_lengths = _assert_stats_match_host(det, dtype)
+        assert run_lengths[1] == rl[0] and run_lengths[2] == rl[2]
+        assert run_lengths[3] == rl[0]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "float64"])
+def test_pallas_bocd_retuned_step_bitmatches_bocd_step(dtype):
+    """After ``retune(hazard=...)``, and after a restore across it, the
+    next steps equal ``bocd_step`` given the new hazard as a Python float,
+    bit for bit."""
+    with jax.enable_x64(dtype == "float64"):
+        det, x = _stats_run(jnp.dtype(dtype))
+        snap = det.snapshot()
+        det.retune(hazard=0.0371)
+        for run in range(2):
+            for t in range(len(x)):
+                state = (det._log_r, det._mu, det._beta, det._kappa,
+                         det._alpha, det._rl)
+                want = bk.bocd_step(
+                    jnp.asarray(x[t], det.dtype), *state, det._mu0, 0.0371,
+                    det.kappa0, det.alpha0, det.beta0, det.truncation,
+                    interpret=True,
+                )
+                p0 = det.update(x[t])
+                got = (det._log_r, det._mu, det._beta, det._kappa,
+                       det._alpha, det._rl, p0)
+                for g, w in zip(got, want, strict=True):
+                    np.testing.assert_array_equal(
+                        np.asarray(g), np.asarray(w).reshape(g.shape)
+                    )
+            retuned = det.snapshot()
+            det.restore(snap)
+            assert det.hazard != 0.0371
+            det.restore(retuned)
+
+
+def test_pallas_bocd_statistics_compile_with_the_first_update():
+    """A tick that asks for a statistic, with any window, compiles
+    nothing once the detector has stepped at its shape: the first flag may
+    come long after set-up."""
+    b, k = 37, 5
+    det = bk.PallasBOCD(b, max_hypotheses=k, interpret=True)
+    det.update(np.ones(b))
+    compiles = []
+
+    def on_event(event, secs, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(secs)
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        for window in (2, 7, 10_000):
+            det.update(np.full(b, 1.1))
+            det.p_recent_change(window)
+            det.map_runlength()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    assert compiles == []
+
 # ---------------------------------------------- simulator cell reduce
 def _reduce_inputs(pp, tp, dp, seed=0):
     rng = np.random.default_rng(seed)
