@@ -48,7 +48,6 @@ class ArchConfig:
     moe_d_ff: int = 0  # per-expert hidden width (d_ff is the dense-MLP width)
     num_shared_experts: int = 0
     shared_d_ff: int = 0
-    capacity_factor: float = 1.25
     #: "experts" = expert-parallel (E % tp == 0), "ff" = TP within experts
     moe_shard: str = "experts"
     #: pad the routed-expert count up to this (0 = no padding). Dummy
@@ -56,6 +55,12 @@ class ArchConfig:
     #: 60 -> 64 lets qwen2-moe use the expert-parallel path (EXPERIMENTS
     #: §Perf) at +6.7 % expert-weight memory.
     pad_experts_to: int = 0
+    #: the routed experts this host holds and computes, ``[expert_offset,
+    #: expert_offset + experts_held)`` (0 = all of them). The router still
+    #: scores every expert; the rest are other hosts' share (expert
+    #: parallelism across hosts), and what they would add is left out.
+    experts_held: int = 0
+    expert_offset: int = 0
 
     # --- SSM (Mamba2/SSD) ---
     ssm_state: int = 0  # N
@@ -64,6 +69,7 @@ class ArchConfig:
     ssm_groups: int = 1  # G (B/C groups)
     ssm_conv_width: int = 4
     ssm_chunk: int = 128  # SSD chunk length
+    ssm_conv_bias: bool = False
 
     # --- positions / attention variants ---
     pos_encoding: str = "rope"  # rope | mrope | none
@@ -71,6 +77,16 @@ class ArchConfig:
     sliding_window: int = 0  # 0 = full attention; >0 = serve-time window
     #: long_500k policy: "native" (SSM/hybrid), "sliding" (dense w/ window)
     long_context: str = "sliding"
+    #: attention's softmax scale (0 = ``head_dim ** -0.5``)
+    attention_multiplier: float = 0.0
+
+    # --- Granite scalars (1.0 = none) and the tied output head ---
+    embedding_multiplier: float = 1.0
+    #: each sub-layer adds ``residual_multiplier * f(norm(x))``
+    residual_multiplier: float = 1.0
+    #: logits are divided by this
+    logits_scaling: float = 1.0
+    tie_word_embeddings: bool = False
 
     # --- modality stub (vlm / audio carve-out) ---
     modality: str = "text"  # text | vision_embeds | audio_codes
@@ -85,6 +101,15 @@ class ArchConfig:
     @property
     def padded_experts(self) -> int:
         return max(self.num_experts, self.pad_experts_to)
+
+    @property
+    def held_experts(self) -> int:
+        """Routed experts whose weights this host holds."""
+        return self.experts_held or self.padded_experts
+
+    @property
+    def attn_scale(self) -> float:
+        return self.attention_multiplier or self.resolved_head_dim**-0.5
 
     @property
     def padded_vocab(self) -> int:
@@ -127,6 +152,7 @@ class ArchConfig:
         num_heads = min(self.num_heads, 4) if self.num_heads else 0
         num_kv = max(1, min(self.num_kv_heads, num_heads)) if num_heads else 0
         experts = min(self.num_experts, 4) if self.num_experts else 0
+        held = min(self.experts_held, experts)
         return replace(
             self,
             name=self.name + "-smoke",
@@ -139,6 +165,8 @@ class ArchConfig:
             vocab_size=min(self.vocab_size, 512),
             num_experts=experts,
             pad_experts_to=0,
+            experts_held=held,
+            expert_offset=min(self.expert_offset, experts - held) if held else 0,
             top_k=min(self.top_k, 2) if self.top_k else 0,
             moe_d_ff=min(self.moe_d_ff, 128) if self.moe_d_ff else 0,
             num_shared_experts=min(self.num_shared_experts, 1),
@@ -168,10 +196,14 @@ class ArchConfig:
             total += d * 2 * g * n + d * h  # B, C, dt
             total += inner * d  # out proj
             total += self.ssm_conv_width * inner + 2 * h + inner  # conv, A/D, norm
+            if self.ssm_conv_bias:
+                total += inner + 2 * g * n
         if sub.mlp == "mlp":
             total += 3 * d * self.d_ff
         elif sub.mlp == "moe":
-            e = self.top_k if active else self.num_experts
+            # held experts only; a token meets top_k * held / E of them
+            held = self.experts_held or self.num_experts
+            e = self.top_k * held / self.num_experts if active else held
             total += 3 * d * self.moe_d_ff * e
             total += d * self.num_experts  # router
             if self.num_shared_experts:
@@ -182,7 +214,8 @@ class ArchConfig:
     def _params(self, active: bool) -> float:
         per_period = sum(self._per_layer_params(s, active) for s in self.period)
         total = per_period * self.n_periods
-        total += 2 * self.vocab_size * self.d_model * max(1, self.num_codebooks or 1)
+        tables = 1 if self.tie_word_embeddings else 2
+        total += tables * self.vocab_size * self.d_model * max(1, self.num_codebooks or 1)
         total += self.d_model  # final norm
         return total
 
@@ -202,6 +235,7 @@ _REGISTRY: dict[str, str] = {
     "mistral-nemo-12b": "repro.configs.mistral_nemo_12b",
     "yi-9b": "repro.configs.yi_9b",
     "granite-3-8b": "repro.configs.granite_3_8b",
+    "granite-4.0-h-small": "repro.configs.granite_4_h_small",
     "jamba-1.5-large-398b": "repro.configs.jamba_1p5_large",
     "qwen2-moe-a2.7b": "repro.configs.qwen2_moe_a2p7b",
     "falcon-demo-100m": "repro.configs.falcon_demo_100m",

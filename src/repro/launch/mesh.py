@@ -20,5 +20,8 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
-    """Small mesh over however many (host) devices are available."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    """Small mesh over however many (host) devices are available, its axes
+    in auto mode: the compiler propagates the parameter and batch
+    shardings (``sharding.partition``) through the step."""
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

@@ -2,7 +2,7 @@
 
     PYTHONPATH=src python -m repro.launch.train --arch falcon-demo-100m \
         --steps 50 --seq-len 256 --global-batch 32 [--no-falcon] \
-        [--inject gpu:3:0.5:100:600] [--smoke] [--events]
+        [--inject gpu:3:0.5:100:600] [--smoke] [--events] [--mesh 1x4]
 
 ``--inject kind:target:severity:start:duration`` adds a fail-slow to the
 attached cluster performance model (kind: gpu|cpu|link|nic), a simulated
@@ -11,7 +11,10 @@ mitigation run through :mod:`repro.controlplane`; ``--events`` dumps the
 control plane's typed event log after the run as JSON lines through the
 same :func:`~repro.controlplane.event_log_records` serializer the
 campaign reports use (Observations elided; ``--events-stride N`` samples
-every Nth per-job Observation into the dump).
+every Nth per-job Observation into the dump). ``--mesh DATAxMODEL`` runs
+the step as one program over a (data, model) mesh of the host's devices
+(``FalconTrainer(mesh=...)``), its parameters sharded by
+``sharding.partition``.
 """
 from __future__ import annotations
 
@@ -26,6 +29,7 @@ from repro.configs.base import ArchConfig, get_config
 from repro.controlplane import event_log_records
 from repro.data.pipeline import DataConfig
 from repro.launch import init_compile_cache
+from repro.launch.mesh import make_host_mesh
 from repro.optim.adamw import AdamWConfig
 from repro.train.trainer import FalconTrainer
 
@@ -110,6 +114,9 @@ def main() -> None:
     ap.add_argument("--sim-nodes", type=int, default=None,
                     help="4-GPU nodes of the simulated cluster "
                          "(default: just enough for the job)")
+    ap.add_argument("--mesh", default=None, metavar="DATAxMODEL",
+                    help="run the step over a (data, model) mesh of the host's "
+                         "devices, e.g. 1x4 (default: one device)")
     ap.add_argument(
         "--events", action="store_true",
         help="dump the control plane's typed event log after the run "
@@ -132,10 +139,14 @@ def main() -> None:
         dp_groups=args.dp_groups,
     )
 
+    mesh = None
+    if args.mesh:
+        data_axis, model_axis = (int(v) for v in args.mesh.lower().split("x"))
+        mesh = make_host_mesh(data_axis, model_axis)
     trainer = build_trainer(
         cfg, data, steps=args.steps,
         injections=[parse_injection(t) for t in args.inject],
-        falcon_enabled=not args.no_falcon, sim_nodes=args.sim_nodes,
+        falcon_enabled=not args.no_falcon, sim_nodes=args.sim_nodes, mesh=mesh,
     )
     print(f"# simulated cluster reductions: {trainer.perf_model.reduction_name}")
     history = trainer.run(args.steps)

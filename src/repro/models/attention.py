@@ -42,18 +42,21 @@ def blocked_attention(
     window: int = 0,
     q_offset: int = 0,
     kv_block: int = 1024,
+    scale: float | None = None,
 ) -> jax.Array:
     """Online-softmax attention over KV blocks.
 
     q: (B, Sq, H, hd); k, v: (B, Skv, KVH, hd) with H a multiple of KVH.
     ``window`` > 0 restricts attention to the last ``window`` keys
     (sliding-window). ``q_offset`` is the absolute position of q[0]
-    (for decode/prefill continuation).
+    (for decode/prefill continuation). ``scale`` multiplies the scores
+    (default ``hd ** -0.5``; a config's is ``ArchConfig.attn_scale``).
     """
     b, sq, h, hd = q.shape
     skv, kvh = k.shape[1], k.shape[2]
     rep = h // kvh
-    scale = hd**-0.5
+    if scale is None:
+        scale = hd**-0.5
 
     nblk = -(-skv // kv_block)
     pad = nblk * kv_block - skv
@@ -129,8 +132,10 @@ def apply_attention(
     *,
     window: int = 0,
     use_kernel: bool = False,
-) -> jax.Array:
-    """Training/prefill self-attention. x: (B, S, D)."""
+    return_kv: bool = False,
+):
+    """Training/prefill self-attention. x: (B, S, D). With ``return_kv``
+    returns ``(out, {"k", "v"})``, the prefill's cache."""
     b, s, _ = x.shape
     hd, h, kv = cfg.resolved_head_dim, cfg.num_heads, cfg.num_kv_heads
     hn = layers.rmsnorm(x, params["norm"], cfg.norm_eps)
@@ -141,10 +146,14 @@ def apply_attention(
     if use_kernel:
         from repro.kernels import ops as kernel_ops
 
+        if cfg.attention_multiplier:  # the kernel scales by hd ** -0.5
+            q = q * jnp.asarray(cfg.attn_scale * hd**0.5, q.dtype)
         out = kernel_ops.flash_attention(q, k, v, causal=True, window=window)
     else:
-        out = blocked_attention(q, k, v, causal=True, window=window)
-    return out.reshape(b, s, h * hd) @ params["wo"]
+        out = blocked_attention(q, k, v, causal=True, window=window,
+                                scale=cfg.attn_scale)
+    out = out.reshape(b, s, h * hd) @ params["wo"]
+    return (out, {"k": k, "v": v}) if return_kv else out
 
 
 # ----------------------------------------------------------------- decode
@@ -222,6 +231,8 @@ def decode_attention(
         # valid positions form a prefix of k_att in both branches:
         # full cache -> pos+1; sliding window -> pos+1-start.
         valid_len = jnp.sum(valid).astype(jnp.int32)
+        if cfg.attention_multiplier:  # the kernel scales by hd ** -0.5
+            q = q * jnp.asarray(cfg.attn_scale * hd**0.5, q.dtype)
         out = kernel_ops.flash_decode(
             q.reshape(b, h, hd), k_att, v_att, valid_len
         )
@@ -229,7 +240,7 @@ def decode_attention(
         return out @ params["wo"], {"k": k_cache, "v": v_cache}
 
     rep = h // kv
-    qg = q.reshape(b, kv, rep, hd).astype(jnp.float32) * hd**-0.5
+    qg = q.reshape(b, kv, rep, hd).astype(jnp.float32) * cfg.attn_scale
     s = jnp.einsum("bgrd,bkgd->bgrk", qg, k_att.astype(jnp.float32))
     s = jnp.where(valid[None, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)

@@ -100,10 +100,23 @@ def apply_embed(params: dict, tokens: jax.Array, cfg: ArchConfig) -> jax.Array:
             for k in range(cfg.num_codebooks)
         )
         return out.astype(cfg.activation_dtype)
-    return jnp.take(params["tok"], tokens, axis=0).astype(cfg.activation_dtype)
+    x = jnp.take(params["tok"], tokens, axis=0).astype(cfg.activation_dtype)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+    return x
+
+
+def head_params(params: dict, cfg: ArchConfig) -> dict:
+    """The output head's parameters: with tied embeddings, the embedding
+    table read as a ``(d, vocab)`` matrix (its vocab dim keeps the
+    model-axis sharding the untied head has)."""
+    if cfg.tie_word_embeddings:
+        return {"w": params["embed"]["tok"].T}
+    return params["head"]
 
 
 def head_schema(cfg: ArchConfig) -> Schema:
+    """The untied head (tied configs have none: :func:`head_params`)."""
     v, d = cfg.padded_vocab, cfg.d_model
     if cfg.modality == "audio_codes":
         return {"w": ParamDef((cfg.num_codebooks, d, v), (None, None, "model"))}
@@ -121,6 +134,8 @@ def apply_head(params: dict, x: jax.Array, cfg: ArchConfig) -> jax.Array:
         logits = jnp.einsum("bsd,kdv->bskv", x, params["w"])
     else:
         logits = x @ params["w"]
+    if cfg.logits_scaling != 1.0:
+        logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
     vp = cfg.padded_vocab
     if vp != cfg.vocab_size:
         mask = jnp.arange(vp) < cfg.vocab_size

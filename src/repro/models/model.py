@@ -25,12 +25,14 @@ AUX_LOSS_COEF = 0.01
 
 
 def model_schema(cfg: ArchConfig) -> Schema:
-    return {
+    out = {
         "embed": layers.embed_schema(cfg),
         "blocks": transformer.blocks_schema(cfg),
         "final_norm": layers.rmsnorm_schema(cfg.d_model),
-        "head": layers.head_schema(cfg),
     }
+    if not cfg.tie_word_embeddings:
+        out["head"] = layers.head_schema(cfg)
+    return out
 
 
 def init_params(cfg: ArchConfig, seed: int = 0) -> dict:
@@ -64,6 +66,17 @@ def _positions(batch: dict, cfg: ArchConfig, seq_len: int) -> jax.Array | None:
     return jnp.broadcast_to(jnp.arange(seq_len)[None, :], (bsz, seq_len))
 
 
+def _forward(params, batch, cfg, *, window, use_kernel, remat):
+    x = _embed_inputs(params, batch, cfg)
+    positions = _positions(batch, cfg, x.shape[1])
+    x, aux, moe_stats = transformer.apply_blocks(
+        params["blocks"], x, cfg, positions,
+        window=window, use_kernel=use_kernel, remat=remat,
+    )
+    x = layers.rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    return layers.apply_head(layers.head_params(params, cfg), x, cfg), aux, moe_stats
+
+
 def forward(
     params: dict,
     batch: dict,
@@ -74,14 +87,10 @@ def forward(
     remat: bool = True,
 ) -> tuple[jax.Array, jax.Array]:
     """Full forward pass. Returns (logits, aux_loss)."""
-    x = _embed_inputs(params, batch, cfg)
-    positions = _positions(batch, cfg, x.shape[1])
-    x, aux = transformer.apply_blocks(
-        params["blocks"], x, cfg, positions,
-        window=window, use_kernel=use_kernel, remat=remat,
+    logits, aux, _ = _forward(
+        params, batch, cfg, window=window, use_kernel=use_kernel, remat=remat
     )
-    x = layers.rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    return layers.apply_head(params["head"], x, cfg), aux
+    return logits, aux
 
 
 def loss_fn(
@@ -93,8 +102,11 @@ def loss_fn(
     use_kernel: bool = False,
     remat: bool = True,
 ) -> tuple[jax.Array, dict]:
-    """Mean next-token cross-entropy (+ MoE aux). Returns (loss, metrics)."""
-    logits, aux = forward(
+    """Mean next-token cross-entropy (+ MoE aux). Returns (loss, metrics);
+    with MoE layers the metrics also hold ``moe_counts`` (tokens routed to
+    each held expert, per MoE layer) and ``moe_dropped`` (held-expert
+    assignments left uncomputed: 0 on the dropless path)."""
+    logits, aux, moe_stats = _forward(
         params, batch, cfg, window=window, use_kernel=use_kernel, remat=remat
     )
     labels = batch["labels"]
@@ -103,7 +115,7 @@ def loss_fn(
     ll = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
     ce = jnp.mean(logz - ll)
     loss = ce + AUX_LOSS_COEF * aux
-    return loss, {"ce": ce, "aux": aux}
+    return loss, {"ce": ce, "aux": aux, **moe_stats}
 
 
 # ----------------------------------------------------------------- decode
@@ -131,4 +143,4 @@ def decode_step(
         use_kernel=use_kernel,
     )
     x = layers.rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    return layers.apply_head(params["head"], x, cfg), new_caches
+    return layers.apply_head(layers.head_params(params, cfg), x, cfg), new_caches

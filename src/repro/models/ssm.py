@@ -24,7 +24,14 @@ def mamba_schema(cfg: ArchConfig) -> Schema:
     d = cfg.d_model
     inner, h = cfg.ssm_inner, cfg.ssm_heads
     g, n, w = cfg.ssm_groups, cfg.ssm_state, cfg.ssm_conv_width
+    bias: Schema = {}
+    if cfg.ssm_conv_bias:
+        bias = {
+            "conv_x_bias": ParamDef((inner,), ("model",), init="zeros"),
+            "conv_bc_bias": ParamDef((2 * g * n,), (None,), init="zeros"),
+        }
     return {
+        **bias,
         "norm": layers.rmsnorm_schema(d),
         "w_z": ParamDef((d, inner), (None, "model")),
         "w_x": ParamDef((d, inner), (None, "model")),
@@ -40,13 +47,17 @@ def mamba_schema(cfg: ArchConfig) -> Schema:
     }
 
 
-def causal_conv(x: jax.Array, w: jax.Array) -> jax.Array:
-    """Depthwise causal conv. x: (B, S, C); w: (W, C)."""
+def causal_conv(x: jax.Array, w: jax.Array, bias: jax.Array | None = None) -> jax.Array:
+    """Depthwise causal conv. x: (B, S, C); w: (W, C); bias: (C,), added in
+    float32 so that its gradient, a sum over every token, accumulates in
+    float32."""
     width = w.shape[0]
     xp = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
     s = x.shape[1]
     out = sum(xp[:, i : i + s, :] * w[i] for i in range(width))
-    return out
+    if bias is None:
+        return out
+    return (out.astype(jnp.float32) + bias.astype(jnp.float32)).astype(out.dtype)
 
 
 def ssd_scan(
@@ -74,10 +85,13 @@ def ssd_scan(
     cum = jnp.cumsum(da, axis=2)  # within-chunk cumulative log-decay
 
     # ---- intra-chunk (dual/attention-like form) -------------------------
-    # L[q, k] = exp(cum[q] - cum[k]) for q >= k else 0.
+    # L[q, k] = exp(cum[q] - cum[k]) for q >= k else 0. The mask goes in
+    # before the exp: above the diagonal rel is positive and can overflow
+    # (a 256-step chunk reaches exp(177)), and inf there makes the
+    # gradient NaN even where the forward masks it.
     rel = cum[:, :, :, None, :] - cum[:, :, None, :, :]  # (B,nc,Q,K,H)
     tri = jnp.tril(jnp.ones((chunk, chunk), bool))
-    l_mat = jnp.where(tri[None, None, :, :, None], jnp.exp(rel), 0.0)
+    l_mat = jnp.exp(jnp.where(tri[None, None, :, :, None], rel, -jnp.inf))
     scores = jnp.einsum("bcqgn,bckgn->bcqkg", cc, bc)  # (B,nc,Q,K,G)
     scores = jnp.repeat(scores, rep, axis=-1)  # G -> H
     m = scores * l_mat * dtc[:, :, None, :, :]  # dt applied at source step k
@@ -128,20 +142,26 @@ def apply_mamba(
     cfg: ArchConfig,
     *,
     use_kernel: bool = False,
-) -> jax.Array:
-    """Training/prefill Mamba2 block. x: (B, S, D)."""
+    return_cache: bool = False,
+):
+    """Training/prefill Mamba2 block. x: (B, S, D). With ``return_cache``
+    returns ``(out, cache)``, the prefill's state and conv tails."""
     bsz, s, _ = x.shape
     h, p = cfg.ssm_heads, cfg.ssm_head_dim
-    g, n = cfg.ssm_groups, cfg.ssm_state
+    g, n, w = cfg.ssm_groups, cfg.ssm_state, cfg.ssm_conv_width
 
     hn = layers.rmsnorm(x, params["norm"], cfg.norm_eps)
     z = hn @ params["w_z"]
-    xin = hn @ params["w_x"]
-    bc = hn @ params["w_bc"]
-    dt = jax.nn.softplus(hn @ params["w_dt"] + params["dt_bias"])
+    xin_raw = hn @ params["w_x"]
+    bc_raw = hn @ params["w_bc"]
+    # dt_bias and d_skip enter in float32: their gradients are sums over
+    # every token (and head dim), which would otherwise accumulate in the
+    # activations' bfloat16.
+    dt = jax.nn.softplus((hn @ params["w_dt"]).astype(jnp.float32)
+                         + params["dt_bias"].astype(jnp.float32))
 
-    xin = jax.nn.silu(causal_conv(xin, params["conv_x"]))
-    bc = jax.nn.silu(causal_conv(bc, params["conv_bc"]))
+    xin = jax.nn.silu(causal_conv(xin_raw, params["conv_x"], params.get("conv_x_bias")))
+    bc = jax.nn.silu(causal_conv(bc_raw, params["conv_bc"], params.get("conv_bc_bias")))
     b_mat, c_mat = jnp.split(bc, 2, axis=-1)
 
     a = -jnp.exp(params["a_log"].astype(jnp.float32))
@@ -152,13 +172,17 @@ def apply_mamba(
     if use_kernel:
         from repro.kernels import ops as kernel_ops
 
-        y, _ = kernel_ops.ssd_scan(xh, dt, a, b_mat, c_mat, chunk=cfg.ssm_chunk)
+        y, state = kernel_ops.ssd_scan(xh, dt, a, b_mat, c_mat, chunk=cfg.ssm_chunk)
     else:
-        y, _ = ssd_scan(xh, dt, a, b_mat, c_mat, chunk=cfg.ssm_chunk)
-    y = y + params["d_skip"][:, None] * xh  # per-head skip
+        y, state = ssd_scan(xh, dt, a, b_mat, c_mat, chunk=cfg.ssm_chunk)
+    y = y + (params["d_skip"].astype(jnp.float32)[:, None] * xh).astype(y.dtype)
     y = y.reshape(bsz, s, h * p)
     y = layers.rmsnorm(y * jax.nn.silu(z), params["out_norm"], cfg.norm_eps)
-    return y @ params["w_out"]
+    out = y @ params["w_out"]
+    if not return_cache:
+        return out
+    return out, {"state": state, "conv_x": xin_raw[:, -(w - 1):],
+                 "conv_bc": bc_raw[:, -(w - 1):]}
 
 
 # ----------------------------------------------------------------- decode
@@ -202,8 +226,13 @@ def decode_mamba(
     # Rolling conv caches.
     xin_hist = jnp.concatenate([cache["conv_x"], xin], axis=1)  # (B,W,inner)
     bc_hist = jnp.concatenate([cache["conv_bc"], bc], axis=1)
-    xin = jax.nn.silu(jnp.einsum("bwc,wc->bc", xin_hist, params["conv_x"]))[:, None]
-    bc_c = jax.nn.silu(jnp.einsum("bwc,wc->bc", bc_hist, params["conv_bc"]))[:, None]
+    xin = jnp.einsum("bwc,wc->bc", xin_hist, params["conv_x"])
+    bc_c = jnp.einsum("bwc,wc->bc", bc_hist, params["conv_bc"])
+    if cfg.ssm_conv_bias:
+        xin = xin + params["conv_x_bias"]
+        bc_c = bc_c + params["conv_bc_bias"]
+    xin = jax.nn.silu(xin)[:, None]
+    bc_c = jax.nn.silu(bc_c)[:, None]
     b_mat, c_mat = jnp.split(bc_c, 2, axis=-1)
 
     a = -jnp.exp(params["a_log"].astype(jnp.float32))

@@ -38,7 +38,13 @@ def blocks_schema(cfg: ArchConfig) -> Schema:
     return stack(period_schema(cfg), cfg.n_periods)
 
 
-def _apply_sublayer(
+def _scaled(y: jax.Array, cfg: ArchConfig) -> jax.Array:
+    """A sub-layer's output as the residual adds it (Granite scales it)."""
+    r = cfg.residual_multiplier
+    return y if r == 1.0 else y * jnp.asarray(r, y.dtype)
+
+
+def apply_sublayer(
     x: jax.Array,
     p: dict,
     sub: SubLayer,
@@ -46,21 +52,36 @@ def _apply_sublayer(
     positions: jax.Array | None,
     window: int,
     use_kernel: bool,
-) -> tuple[jax.Array, jax.Array]:
-    """Residual sub-layer application. Returns (x, aux_loss)."""
+    *,
+    want_cache: bool = False,
+) -> tuple[jax.Array, jax.Array, dict | None, dict | None]:
+    """Residual sub-layer application: ``x + r * mixer(norm(x))``, then
+    ``x + r * ffn(norm(x))`` (``r`` = ``cfg.residual_multiplier``; the MoE's
+    ffn is its routed experts plus its shared ones). The mixers run under
+    the ``attn`` / ``mamba`` named scopes. Returns (x, aux_loss, MoE stats
+    or None, the mixer's prefill cache when ``want_cache`` else None)."""
     aux = jnp.zeros((), jnp.float32)
+    stats = cache = None
     if sub.mixer == "attn":
-        x = x + attention.apply_attention(
-            p["attn"], x, cfg, positions, window=window, use_kernel=use_kernel
-        )
+        with jax.named_scope("attn"):
+            y = attention.apply_attention(
+                p["attn"], x, cfg, positions, window=window,
+                use_kernel=use_kernel, return_kv=want_cache,
+            )
     else:
-        x = x + ssm.apply_mamba(p["mamba"], x, cfg, use_kernel=use_kernel)
+        with jax.named_scope("mamba"):
+            y = ssm.apply_mamba(
+                p["mamba"], x, cfg, use_kernel=use_kernel, return_cache=want_cache
+            )
+    if want_cache:
+        y, cache = y
+    x = x + _scaled(y, cfg)
     if sub.mlp == "mlp":
-        x = x + layers.apply_mlp(p["mlp"], x, cfg)
+        x = x + _scaled(layers.apply_mlp(p["mlp"], x, cfg), cfg)
     elif sub.mlp == "moe":
-        y, aux = moe.apply_moe(p["moe"], x, cfg)
-        x = x + y
-    return x, aux
+        y, aux, stats = moe.apply_moe(p["moe"], x, cfg)
+        x = x + _scaled(y, cfg)
+    return x, aux, stats, cache
 
 
 def apply_blocks(
@@ -72,32 +93,50 @@ def apply_blocks(
     window: int = 0,
     use_kernel: bool = False,
     remat: bool = True,
-) -> tuple[jax.Array, jax.Array]:
-    """Run the full stack. Returns (hidden (B,S,D), total aux loss)."""
+) -> tuple[jax.Array, jax.Array, dict]:
+    """Run the full stack. Returns (hidden (B,S,D), total aux loss, MoE
+    stats): with MoE sub-layers ``{"moe_counts": (n_moe_layers,
+    held_experts) int32, "moe_dropped": int32}``, else ``{}``."""
+
+    def sublayer(h, p, sub):
+        return apply_sublayer(h, p, sub, cfg, positions, window, use_kernel)[:3]
+
+    if remat:
+        # Each sub-layer is its own remat boundary, so the backward pass
+        # recomputes one sub-layer's forward at a time (a whole period of
+        # ten would hold ten layers' recomputed activations at once). Save
+        # matmul outputs across it (they're what the backward pass actually
+        # needs); recompute only the cheap elementwise/norm chains.
+        # Full-recompute remat costs ~25-30 % extra FLOPs, and train_4k
+        # peaks far below HBM — memory is the cheaper currency here
+        # (EXPERIMENTS §Perf, granite-3-8b iteration 1).
+        sublayer = jax.checkpoint(
+            sublayer, static_argnums=(2,),
+            policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+        )
 
     def period_body(carry, period_params):
         h, aux_sum = carry
+        counts, dropped = [], jnp.zeros((), jnp.int32)
         for j, sub in enumerate(cfg.period):
-            h, aux = _apply_sublayer(
-                h, period_params[f"sub{j}"], sub, cfg, positions, window, use_kernel
-            )
+            h, aux, stats = sublayer(h, period_params[f"sub{j}"], sub)
             aux_sum = aux_sum + aux
-        return (h, aux_sum), None
+            if stats is not None:
+                counts.append(stats["counts"])
+                dropped = dropped + stats["dropped"]
+        out = {"counts": jnp.stack(counts), "dropped": dropped} if counts else None
+        return (h, aux_sum), out
 
-    if remat:
-        # Save matmul outputs across the remat boundary (they're what the
-        # backward pass actually needs); recompute only the cheap
-        # elementwise/norm chains. Full-recompute remat costs ~25-30 % extra
-        # FLOPs, and train_4k peaks far below HBM — memory is the cheaper
-        # currency here (EXPERIMENTS §Perf, granite-3-8b iteration 1).
-        body = jax.checkpoint(
-            period_body,
-            policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-        )
-    else:
-        body = period_body
-    (x, aux_total), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), blocks)
-    return x, aux_total
+    (x, aux_total), per_period = jax.lax.scan(
+        period_body, (x, jnp.zeros((), jnp.float32)), blocks
+    )
+    if per_period is None:
+        return x, aux_total, {}
+    counts = per_period["counts"]  # (n_periods, moe sub-layers, held)
+    return x, aux_total, {
+        "moe_counts": counts.reshape(-1, counts.shape[-1]),
+        "moe_dropped": jnp.sum(per_period["dropped"]),
+    }
 
 
 # ----------------------------------------------------------------- decode
@@ -178,13 +217,13 @@ def decode_blocks(
                 dh, nc = ssm.decode_mamba(
                     period_params[key]["mamba"], h, cache[key], cfg
                 )
-            h = h + dh
+            h = h + _scaled(dh, cfg)
             new_cache[key] = nc
             if sub.mlp == "mlp":
-                h = h + layers.apply_mlp(period_params[key]["mlp"], h, cfg)
+                h = h + _scaled(layers.apply_mlp(period_params[key]["mlp"], h, cfg), cfg)
             elif sub.mlp == "moe":
-                y, _ = moe.apply_moe(period_params[key]["moe"], h, cfg)
-                h = h + y
+                y, _, _ = moe.apply_moe(period_params[key]["moe"], h, cfg)
+                h = h + _scaled(y, cfg)
         return h, new_cache
 
     x, new_caches = jax.lax.scan(period_body, x, (blocks, caches))

@@ -11,10 +11,9 @@ from __future__ import annotations
 from typing import Callable
 
 import jax
-import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
-from repro.models import attention, layers, model as model_lib, ssm, transformer
+from repro.models import layers, model as model_lib, transformer
 
 
 def serve_window(cfg: ArchConfig, seq_len: int) -> int:
@@ -51,78 +50,18 @@ def make_prefill_step(cfg: ArchConfig, seq_len: int) -> Callable:
         )
         positions = model_lib._positions(batch, cfg, x.shape[1])
 
-        def period_body(carry, period_params):
-            h = carry
+        def period_body(h, period_params):
             cache_out = {}
             for j, sub in enumerate(cfg.period):
-                key = f"sub{j}"
-                p = period_params[key]
-                if sub.mixer == "attn":
-                    dh, c = _prefill_attention(p["attn"], h, cfg, positions, window)
-                else:
-                    dh, c = _prefill_mamba(p["mamba"], h, cfg)
-                h = h + dh
-                cache_out[key] = c
-                if sub.mlp == "mlp":
-                    h = h + layers.apply_mlp(p["mlp"], h, cfg)
-                elif sub.mlp == "moe":
-                    from repro.models import moe
-
-                    y, _ = moe.apply_moe(p["moe"], h, cfg)
-                    h = h + y
+                h, _, _, cache_out[f"sub{j}"] = transformer.apply_sublayer(
+                    h, period_params[f"sub{j}"], sub, cfg, positions, window,
+                    False, want_cache=True,
+                )
             return h, cache_out
 
         h, caches = jax.lax.scan(period_body, x, params["blocks"])
         h = layers.rmsnorm(h, params["final_norm"], cfg.norm_eps)
-        logits = layers.apply_head(params["head"], h[:, -1:], cfg)
+        logits = layers.apply_head(layers.head_params(params, cfg), h[:, -1:], cfg)
         return logits, caches
 
     return prefill
-
-
-def _prefill_attention(p, x, cfg, positions, window):
-    b, s, _ = x.shape
-    hd, h, kv = cfg.resolved_head_dim, cfg.num_heads, cfg.num_kv_heads
-    hn = layers.rmsnorm(x, p["norm"], cfg.norm_eps)
-    q = (hn @ p["wq"]).reshape(b, s, h, hd)
-    k = (hn @ p["wk"]).reshape(b, s, kv, hd)
-    v = (hn @ p["wv"]).reshape(b, s, kv, hd)
-    q, k = attention._apply_positions(q, k, positions, cfg)
-    out = attention.blocked_attention(q, k, v, causal=True, window=window)
-    return out.reshape(b, s, h * hd) @ p["wo"], {"k": k, "v": v}
-
-
-def _prefill_mamba(p, x, cfg):
-    b, s, _ = x.shape
-    h, pd = cfg.ssm_heads, cfg.ssm_head_dim
-    g, n, w = cfg.ssm_groups, cfg.ssm_state, cfg.ssm_conv_width
-
-    hn = layers.rmsnorm(x, p["norm"], cfg.norm_eps)
-    z = hn @ p["w_z"]
-    xin_raw = hn @ p["w_x"]
-    bc_raw = hn @ p["w_bc"]
-    dt = jax.nn.softplus(hn @ p["w_dt"] + p["dt_bias"])
-
-    xin = jax.nn.silu(ssm.causal_conv(xin_raw, p["conv_x"]))
-    bc = jax.nn.silu(ssm.causal_conv(bc_raw, p["conv_bc"]))
-    b_mat, c_mat = jnp.split(bc, 2, axis=-1)
-
-    a = -jnp.exp(p["a_log"].astype(jnp.float32))
-    xh = xin.reshape(b, s, h, pd)
-    y, final_state = ssm.ssd_scan(
-        xh,
-        dt,
-        a,
-        b_mat.reshape(b, s, g, n),
-        c_mat.reshape(b, s, g, n),
-        chunk=cfg.ssm_chunk,
-    )
-    y = y + p["d_skip"][:, None] * xh
-    y = y.reshape(b, s, h * pd)
-    y = layers.rmsnorm(y * jax.nn.silu(z), p["out_norm"], cfg.norm_eps)
-    cache = {
-        "state": final_state,
-        "conv_x": xin_raw[:, -(w - 1) :],
-        "conv_bc": bc_raw[:, -(w - 1) :],
-    }
-    return y @ p["w_out"], cache

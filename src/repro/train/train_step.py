@@ -33,6 +33,11 @@ from repro.optim import adamw
 from repro.sharding import partition
 
 
+#: the loss metrics a step returns besides the loss: per MoE layer and held
+#: expert the tokens routed to it, and the held assignments not computed
+MOE_COUNTERS = ("moe_counts", "moe_dropped")
+
+
 def _microbatch_loss(params, mb, cfg: ArchConfig, use_kernel: bool):
     return model_lib.loss_fn(params, mb, cfg, use_kernel=use_kernel)
 
@@ -54,13 +59,18 @@ def make_train_step(
             (loss, metrics), grads = jax.value_and_grad(
                 lambda p: _microbatch_loss(p, mb, cfg, use_kernel), has_aux=True
             )(params)
-            return (jax.tree.map(jnp.add, gsum, grads), lsum + loss), None
+            counters = {k: metrics[k] for k in MOE_COUNTERS if k in metrics}
+            return (jax.tree.map(jnp.add, gsum, grads), lsum + loss), counters
 
         g0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
-        (gsum, lsum), _ = jax.lax.scan(body, (g0, jnp.float32(0)), jnp.arange(slots))
+        (gsum, lsum), counters = jax.lax.scan(
+            body, (g0, jnp.float32(0)), jnp.arange(slots)
+        )
         grads = jax.tree.map(lambda g: g / slots, gsum)
         params, opt_state = adamw.update(opt_cfg, grads, opt_state, params)
-        return params, opt_state, {"loss": lsum / slots}
+        # MoE routing counts ride out with the loss, summed over the slots.
+        counters = {k: jnp.sum(v, axis=0) for k, v in counters.items()}
+        return params, opt_state, {"loss": lsum / slots, **counters}
 
     return train_step
 

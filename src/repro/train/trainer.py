@@ -28,14 +28,26 @@ holding ``train.batch`` (``make_batch`` and the upload),
 ``train.dispatch`` and ``train.loss_sync`` (together what
 ``StepRecord.measured`` times), ``train.simulate`` (the performance
 model's iteration time), ``controlplane.observe`` and ``train.mitigate``.
+
+With a ``mesh`` (``launch.mesh.make_host_mesh``) the trainer is one SPMD
+program over its devices: set-up places parameters and optimizer state by
+``sharding.partition.param_specs`` (span ``train.place``), each batch is
+put by ``train_batch_specs``, and the step is traced under
+``jax.set_mesh`` so the expert-parallel MoE path sees the mesh. Without
+one, everything stays on the default device. For a model with MoE layers
+the trainer counts routing (``moe_routed_tokens``, ``moe_expert_load_max``,
+``moe_dropped_tokens``) from the counts its step returns with the loss;
+each ``train.loss_sync`` carries their values as of its start.
 """
 from __future__ import annotations
 
+import contextlib
 import os
 import tempfile
 import time
 import warnings
 from dataclasses import dataclass, field
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -52,6 +64,7 @@ from repro.data.pipeline import DataConfig, make_batch
 from repro.models import model as model_lib
 from repro.obs.host import span, step as step_span
 from repro.optim import adamw
+from repro.sharding import partition
 from repro.train import train_step as ts_lib
 from repro.train.checkpoint import CheckpointManager
 
@@ -82,6 +95,8 @@ class FalconTrainer:
         default_factory=lambda: os.path.join(tempfile.gettempdir(), "repro_ckpt")
     )
     seed: int = 0
+    #: a ``(data, model)`` mesh to run the step over (None: one device)
+    mesh: Any = None
 
     params: dict = field(init=False)
     opt_state: adamw.AdamWState = field(init=False)
@@ -90,10 +105,21 @@ class FalconTrainer:
     history: list[StepRecord] = field(init=False, default_factory=list)
     allocation: list[int] = field(init=False)
     _wall: float = field(init=False, default=0.0)
+    #: parameter shardings on the mesh (None without one)
+    param_shardings: Any = field(init=False, default=None)
+    #: routing counters (models with MoE layers): tokens routed to held
+    #: experts, the sum over steps of the busiest (layer, expert)'s tokens,
+    #: and held assignments left uncomputed
+    moe_routed_tokens: int = field(init=False, default=0)
+    moe_expert_load_max: int = field(init=False, default=0)
+    moe_dropped_tokens: int = field(init=False, default=0)
 
     def __post_init__(self) -> None:
-        self.params = model_lib.init_params(self.cfg, self.seed)
-        self.opt_state = adamw.init(self.params)
+        if self.mesh is None:
+            self.params = model_lib.init_params(self.cfg, self.seed)
+            self.opt_state = adamw.init(self.params)
+        else:
+            self._place()
         self.ckpt = CheckpointManager(self.ckpt_dir)
         self.allocation = [self.data.slots] * self.data.dp_groups
         if self.perf_model is not None:
@@ -106,12 +132,59 @@ class FalconTrainer:
                 injector=self.injector,
             )
             self.detector = self._job.detector
+        self._step_fn = self.jit_step()
+
+    def jit_step(self):
+        """The step as the trainer runs it, traced afresh when first called
+        or lowered (under ``jax.set_mesh(self.mesh)`` on a mesh)."""
         # Donate params and optimizer state: the step's outputs reuse their
         # buffers, so the device holds one copy of each, not two.
-        self._step_fn = jax.jit(
-            ts_lib.make_train_step(self.cfg, self.opt_cfg),
-            donate_argnums=(0, 1),
+        placed = {} if self.mesh is None else {
+            "in_shardings": (self.param_shardings, self._opt_shardings,
+                             self._batch_shardings),
+            "out_shardings": (self.param_shardings, self._opt_shardings, None),
+        }
+        return jax.jit(
+            ts_lib.make_train_step(self.cfg, self.opt_cfg), donate_argnums=(0, 1),
+            **placed,
         )
+
+    def _place(self) -> None:
+        """Parameters and optimizer state made directly in their shardings
+        on the mesh (no device ever holds the whole model)."""
+        with span("train.place"):
+            mesh = self.mesh
+            specs = partition.param_specs(self.cfg, mesh)
+            shapes = model_lib.param_shapes(self.cfg)
+            self.param_shardings = partition.named(specs, mesh)
+            self._opt_shardings = partition.named(
+                adamw.opt_state_specs(specs, shapes, mesh), mesh
+            )
+            self._batch_shardings = partition.named(
+                partition.train_batch_specs(self.cfg, mesh), mesh
+            )
+            self.params = jax.jit(
+                lambda: model_lib.init_params(self.cfg, self.seed),
+                out_shardings=self.param_shardings,
+            )()
+            self.opt_state = jax.jit(
+                adamw.init, out_shardings=self._opt_shardings
+            )(self.params)
+
+    def _moe_counters(self) -> dict:
+        return {"moe_routed_tokens": self.moe_routed_tokens,
+                "moe_expert_load_max": self.moe_expert_load_max,
+                "moe_dropped_tokens": self.moe_dropped_tokens}
+
+    def _read_loss_and_routing(self, metrics: dict) -> float:
+        """The loss and the step's routing counts, in one read."""
+        loss, counts, dropped = jax.device_get(
+            (metrics["loss"], metrics["moe_counts"], metrics["moe_dropped"])
+        )
+        self.moe_routed_tokens += int(counts.sum())
+        self.moe_expert_load_max += int(counts.max())
+        self.moe_dropped_tokens += int(dropped)
+        return float(loss)
 
     @property
     def planner(self):
@@ -163,6 +236,8 @@ class FalconTrainer:
                 # ran inside CkptRestartStrategy.
                 self.ckpt.save_memory(self.params)
                 self.params = self.ckpt.restore_memory()
+                if self.mesh is not None:
+                    self.params = jax.device_put(self.params, self.param_shardings)
                 self.allocation = [self.data.slots] * self.data.dp_groups
 
     # ------------------------------------------------------------------
@@ -177,16 +252,23 @@ class FalconTrainer:
 
     def _step(self, step: int, k: int) -> StepRecord:
         with span("train.batch", step=k):
-            batch = jax.tree.map(
-                jnp.asarray, make_batch(self.cfg, self.data, step)
-            )
+            batch = make_batch(self.cfg, self.data, step)
+            if self.mesh is None:
+                batch = jax.tree.map(jnp.asarray, batch)
+            else:
+                batch = jax.device_put(batch, self._batch_shardings)
         t0 = time.monotonic()
-        with span("train.dispatch", step=k):
+        on_mesh = contextlib.nullcontext() if self.mesh is None else jax.set_mesh(self.mesh)
+        with span("train.dispatch", step=k), on_mesh:
             self.params, self.opt_state, metrics = self._step_fn(
                 self.params, self.opt_state, batch
             )
-        with span("train.loss_sync", step=k):
-            loss = float(metrics["loss"])
+        if "moe_counts" in metrics:
+            with span("train.loss_sync", step=k, **self._moe_counters()):
+                loss = self._read_loss_and_routing(metrics)
+        else:
+            with span("train.loss_sync", step=k):
+                loss = float(metrics["loss"])
         measured = time.monotonic() - t0
 
         with span("train.simulate", step=k):

@@ -134,6 +134,7 @@ def test_full_config_parameter_count_sane(arch):
         "mistral-nemo-12b": 12e9,
         "yi-9b": 8.8e9,
         "granite-3-8b": 8e9,
+        "granite-4.0-h-small": 32e9,
         "jamba-1.5-large-398b": 398e9,
         "qwen2-moe-a2.7b": 14.3e9,
     }[arch]

@@ -3,8 +3,8 @@
 Subprocess with 4 host devices: the same params/tokens are run through
 ``apply_moe`` (a) with no mesh (dense-host path) and (b) under a
 (data=2, model=2) mesh where E % model == 0 engages the EP shard_map path.
-With a generous capacity factor (no token drops — per-shard capacity is the
-one intentional semantic difference), outputs must agree.
+The dispatch is dropless on both paths, so outputs, the aux loss and the
+per-expert routing counts must agree.
 """
 import os
 
@@ -15,7 +15,6 @@ import sys
 SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-import dataclasses
 import numpy as np
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -25,7 +24,6 @@ from repro.models import moe
 from repro.models.schema import init_tree
 
 cfg = get_config("olmoe-1b-7b").smoke()
-cfg = dataclasses.replace(cfg, capacity_factor=8.0)  # no drops
 assert cfg.num_experts % 2 == 0 and cfg.moe_shard == "experts"
 
 schema = moe.moe_schema(cfg)
@@ -33,25 +31,27 @@ params = init_tree(schema, jax.random.key(0))
 x = jax.random.normal(jax.random.key(1), (4, 16, cfg.d_model), jnp.float32).astype(cfg.activation_dtype)
 
 # (a) dense-host path (no ambient mesh).
-y_ref, aux_ref = jax.jit(lambda p, h: moe.apply_moe(p, h, cfg))(params, x)
+y_ref, aux_ref, st_ref = jax.jit(lambda p, h: moe.apply_moe(p, h, cfg))(params, x)
 
 # (b) expert-parallel path under the mesh, entered with jax.set_mesh as
 # the product code does.
 mesh = jax.make_mesh((2, 2), ("data", "model"))
 with jax.set_mesh(mesh):
-    y_ep, aux_ep = jax.jit(lambda p, h: moe.apply_moe(p, h, cfg))(params, x)
+    y_ep, aux_ep, st_ep = jax.jit(lambda p, h: moe.apply_moe(p, h, cfg))(params, x)
 
 np.testing.assert_allclose(
     np.asarray(y_ref, np.float32), np.asarray(y_ep, np.float32),
     rtol=3e-2, atol=3e-2)
 np.testing.assert_allclose(float(aux_ref), float(aux_ep), rtol=1e-2, atol=1e-2)
+np.testing.assert_array_equal(np.asarray(st_ref["counts"]), np.asarray(st_ep["counts"]))
+assert int(st_ref["dropped"]) == int(st_ep["dropped"]) == 0
 
 # (c) batch=1 (long_500k decode regime): EP must fall back to
 # model-only manual axes and still agree.
 x1 = x[:1, :1]
-y1_ref, _ = jax.jit(lambda p, h: moe.apply_moe(p, h, cfg))(params, x1)
+y1_ref, _, _ = jax.jit(lambda p, h: moe.apply_moe(p, h, cfg))(params, x1)
 with jax.set_mesh(mesh):
-    y1_ep, _ = jax.jit(lambda p, h: moe.apply_moe(p, h, cfg))(params, x1)
+    y1_ep, _, _ = jax.jit(lambda p, h: moe.apply_moe(p, h, cfg))(params, x1)
 np.testing.assert_allclose(
     np.asarray(y1_ref, np.float32), np.asarray(y1_ep, np.float32),
     rtol=3e-2, atol=3e-2)
