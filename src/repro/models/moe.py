@@ -5,7 +5,10 @@ the experts this host holds are sorted by expert, and each held expert's
 SwiGLU FFN runs as one grouped product (``jax.lax.ragged_dot``) over its
 contiguous run of rows, combined back with the router gates. No capacity
 buffer: every assignment to a held expert is computed, however unbalanced
-the routing (at most ``min(top_k, held)`` rows a token, a static bound).
+the routing. The rows a dispatch runs come from a static ladder
+(:func:`dispatch_rungs`): the bound ``t * min(top_k, held)`` (a token's
+top-k are distinct), then halves of it down to the load uniform routing
+would bring; each layer takes the smallest rung that holds its assignments.
 
 Held experts: ``cfg.held_experts`` of them from ``cfg.expert_offset``
 (all by default). An expert outside that share belongs to another host;
@@ -19,6 +22,9 @@ Sharding modes (resolved against the model axis):
     (used when the experts do not divide the axis).
 """
 from __future__ import annotations
+
+import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -85,6 +91,81 @@ def expert_ffn(params: dict, xs: jax.Array, group_sizes: jax.Array) -> jax.Array
     return jax.lax.ragged_dot(jax.nn.silu(gate) * up, params["wo"], group_sizes)
 
 
+def dispatch_rungs(t: int, k: int, e_local: int, num_experts: int) -> tuple[int, ...]:
+    """The row counts a dispatch of ``t`` tokens to ``e_local`` of
+    ``num_experts`` experts may run, largest first. The first is the
+    dropless bound ``t * min(k, e_local)``; each next one halves it, rounded
+    up to a multiple of 128, while the half still holds the assignments
+    uniform routing brings, ``t * k * e_local / num_experts``. With every
+    expert held that is the bound itself: one rung."""
+    full = t * min(k, e_local)
+    expected = t * k * e_local / num_experts
+    rungs = [full]
+    half = full / 2
+    while 0 < expected <= half:
+        rung = min(full, math.ceil(half / 128) * 128)
+        if rung < rungs[-1]:
+            rungs.append(rung)
+        half /= 2
+    return tuple(rungs)
+
+
+def rung_index(rungs: tuple[int, ...], held: jax.Array) -> jax.Array:
+    """Index into ``rungs`` (largest first) of the smallest that holds
+    ``held`` rows; the first, the dropless bound, always does."""
+    return jnp.sum(jnp.asarray(rungs[1:]) >= held).astype(jnp.int32)
+
+
+def _dispatch(rows, k, w, xf, flat_g, order, group_sizes):
+    """Gather, grouped SwiGLU and gated scatter-add combine over the first
+    ``rows`` entries of ``order`` (the held assignments sorted first, by
+    expert): the partial output (T, D) f32."""
+    t, d = xf.shape
+    order = order[:rows]
+    token_of = order // k
+    # Rows past the held assignments are padding, which the grouped
+    # product leaves undefined: they are masked with ``where`` on the way
+    # in and before any use of the output (a zero gate would not do: NaN *
+    # 0 is NaN, in the output and in the gates' gradient alike).
+    valid = (jnp.arange(rows) < jnp.sum(group_sizes))[:, None]
+    xs = jnp.where(valid, xf[token_of], 0.0)
+    out = jnp.where(valid, expert_ffn(w, xs, group_sizes).astype(jnp.float32), 0.0)
+    return jnp.zeros((t, d), jnp.float32).at[token_of].add(out * flat_g[order][:, None])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _laddered(rungs, k, w, xf, flat_g, order, group_sizes, rung):
+    """:func:`_dispatch` at ``rungs[rung]`` rows, one branch a rung. Its
+    own VJP switches on the same rung and keeps only the inputs, the order
+    and the rung: differentiated through ``lax.switch``, every branch's
+    residuals would be kept, those of the branches not taken zero-filled
+    at their full sizes."""
+    branches = [functools.partial(_dispatch, r, k) for r in rungs]
+    return jax.lax.switch(rung, branches, w, xf, flat_g, order, group_sizes)
+
+
+def _laddered_fwd(rungs, k, w, xf, flat_g, order, group_sizes, rung):
+    y = _laddered(rungs, k, w, xf, flat_g, order, group_sizes, rung)
+    return y, (w, xf, flat_g, order, group_sizes, rung)
+
+
+def _laddered_bwd(rungs, k, res, dy):
+    w, xf, flat_g, order, group_sizes, rung = res
+
+    def grads(rows, dy, w, xf, flat_g):
+        _, vjp = jax.vjp(
+            lambda w, xf, g: _dispatch(rows, k, w, xf, g, order, group_sizes), w, xf, flat_g
+        )
+        return vjp(dy)
+
+    branches = [functools.partial(grads, r) for r in rungs]
+    dw, dxf, dg = jax.lax.switch(rung, branches, dy, w, xf, flat_g)
+    return dw, dxf, dg, None, None, None
+
+
+_laddered.defvjp(_laddered_fwd, _laddered_bwd)
+
+
 def _moe_core(
     params: dict,
     xf: jax.Array,
@@ -96,14 +177,15 @@ def _moe_core(
     [e_offset, e_offset + e_local). Returns the *partial* combined output
     (T, D) f32 (contributions of those experts only), the aux loss and
     ``{"counts": (e_local,) tokens each expert computed, "dropped":
-    assignments to these experts left uncomputed}``.
+    assignments to these experts left uncomputed, "rows": rows the
+    dispatch ran}``.
 
     The expert-parallel path calls it per model-axis shard with that
     shard's experts, so dispatch and combine stay device-local (the
     cross-shard reduction is one psum of the partial output — see
     apply_moe).
     """
-    t, d = xf.shape
+    t = xf.shape[0]
     k = cfg.top_k
     gates, idx, aux = route(xf @ params["router"], k, n_real=cfg.num_experts)
 
@@ -114,23 +196,26 @@ def _moe_core(
     local = flat_e - e_offset
     held_assignment = (local >= 0) & (local < e_local)
     keys = jnp.where(held_assignment, local, e_local)
-    # A token's top-k experts are distinct, so at most min(k, e_local) of
-    # its assignments are held: the held ones sort into these rows.
-    rows = t * min(k, e_local)
-    order = jnp.argsort(keys, stable=True)[:rows]
+    order = jnp.argsort(keys, stable=True)
     group_sizes = jnp.bincount(keys, length=e_local + 1)[:e_local].astype(jnp.int32)
-    token_of = order // k
-    # Rows past the held assignments are padding, which the grouped
-    # product leaves undefined: they are masked with ``where`` on the way
-    # in and before any use of the output (a zero gate would not do: NaN *
-    # 0 is NaN, in the output and in the gates' gradient alike).
-    valid = (keys[order] < e_local)[:, None]
-    xs = jnp.where(valid, xf[token_of], 0.0)
-    out = jnp.where(valid, expert_ffn(params, xs, group_sizes).astype(jnp.float32), 0.0)
-    y = jnp.zeros((t, d), jnp.float32).at[token_of].add(out * flat_g[order][:, None])
+    w = {name: params[name] for name in ("wi_gate", "wi_up", "wo")}
+    rungs = dispatch_rungs(t, k, e_local, cfg.num_experts)
+    if len(rungs) == 1:
+        y = _dispatch(rungs[0], k, w, xf, flat_g, order, group_sizes)
+        rows = jnp.int32(rungs[0])
+    else:
+        rung = rung_index(rungs, jnp.sum(group_sizes))
+        y = _laddered(rungs, k, w, xf, flat_g, order, group_sizes, rung)
+        rows = jnp.asarray(rungs, jnp.int32)[rung]
     held = jnp.sum(held_assignment).astype(jnp.int32)
-    stats = {"counts": group_sizes, "dropped": held - jnp.sum(group_sizes)}
+    stats = {"counts": group_sizes, "dropped": held - jnp.sum(group_sizes), "rows": rows}
     return y, aux, stats
+
+
+def _rows(stats: dict, t: int, cfg: ArchConfig, e_local: int):
+    """The rows a core's dispatch ran; the dropless bound for a core that
+    does not say."""
+    return stats.get("rows", jnp.int32(t * min(cfg.top_k, e_local)))
 
 
 def _combine(y: jax.Array) -> jax.Array:
@@ -169,9 +254,10 @@ def apply_moe(
 ) -> tuple[jax.Array, jax.Array, dict]:
     """Returns (output (B,S,D), aux_loss scalar, stats): ``stats["counts"]``
     (held_experts,) tokens routed to each held expert, ``stats["dropped"]``
-    held assignments not computed (0: the dispatch is dropless). The routed
-    experts run under the ``moe`` named scope, the shared expert under
-    ``shared_mlp``.
+    held assignments not computed (0: the dispatch is dropless),
+    ``stats["rows"]`` rows the dispatch ran, summed over the model ranks.
+    The routed experts run under the ``moe`` named scope, the shared expert
+    under ``shared_mlp``.
 
     Under a mesh with a model axis dividing the held experts (and
     ``moe_shard="experts"``), dispatch/combine run *shard-locally* inside a
@@ -195,6 +281,7 @@ def apply_moe(
             y = y.astype(x.dtype)
         if cfg.num_shared_experts:
             y = y + _shared(params, xf).astype(x.dtype)
+        stats = dict(stats, rows=_rows(stats, b * s, cfg, cfg.held_experts))
         return y.reshape(b, s, d), aux, stats
 
     mesh, ba, tp = ep
@@ -234,20 +321,21 @@ def apply_moe(
             y = y + _shared(p, xf).astype(jnp.float32)
         y = _combine(y)
         aux = jax.lax.pmean(aux, ("model", *ba))  # identical across manual ranks
-        counts, dropped = stats["counts"], jax.lax.psum(stats["dropped"], "model")
+        counts = stats["counts"]
+        dropped = jax.lax.psum(stats["dropped"], "model")
+        rows = jax.lax.psum(_rows(stats, bl * sl, cfg, e_local), "model")
         if split:
-            counts = jax.lax.psum(counts, ba)
-            dropped = jax.lax.psum(dropped, ba)
-        return y.astype(h.dtype).reshape(bl, sl, d), aux, counts, dropped
+            counts, dropped, rows = jax.lax.psum((counts, dropped, rows), ba)
+        return y.astype(h.dtype).reshape(bl, sl, d), aux, counts, dropped, rows
 
     manual = frozenset(("model", *ba))
     shmapped = jax.shard_map(
         ep_body,
         mesh=mesh,
         in_specs=(wspec, bspec),
-        out_specs=(bspec, P(), P("model"), P()),
+        out_specs=(bspec, P(), P("model"), P(), P()),
         axis_names=manual,
         check_vma=False,
     )
-    y, aux, counts, dropped = shmapped({k: params[k] for k in wspec}, hn)
-    return y, aux, {"counts": counts, "dropped": dropped}
+    y, aux, counts, dropped, rows = shmapped({k: params[k] for k in wspec}, hn)
+    return y, aux, {"counts": counts, "dropped": dropped, "rows": rows}

@@ -96,7 +96,8 @@ def apply_blocks(
 ) -> tuple[jax.Array, jax.Array, dict]:
     """Run the full stack. Returns (hidden (B,S,D), total aux loss, MoE
     stats): with MoE sub-layers ``{"moe_counts": (n_moe_layers,
-    held_experts) int32, "moe_dropped": int32}``, else ``{}``."""
+    held_experts) int32, "moe_dropped": int32, "moe_rows": int32}``, else
+    ``{}``."""
 
     def sublayer(h, p, sub):
         return apply_sublayer(h, p, sub, cfg, positions, window, use_kernel)[:3]
@@ -117,14 +118,17 @@ def apply_blocks(
 
     def period_body(carry, period_params):
         h, aux_sum = carry
-        counts, dropped = [], jnp.zeros((), jnp.int32)
+        counts, dropped, rows = [], jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32)
         for j, sub in enumerate(cfg.period):
             h, aux, stats = sublayer(h, period_params[f"sub{j}"], sub)
             aux_sum = aux_sum + aux
             if stats is not None:
                 counts.append(stats["counts"])
                 dropped = dropped + stats["dropped"]
-        out = {"counts": jnp.stack(counts), "dropped": dropped} if counts else None
+                rows = rows + stats["rows"]
+        out = None
+        if counts:
+            out = {"counts": jnp.stack(counts), "dropped": dropped, "rows": rows}
         return (h, aux_sum), out
 
     (x, aux_total), per_period = jax.lax.scan(
@@ -136,6 +140,7 @@ def apply_blocks(
     return x, aux_total, {
         "moe_counts": counts.reshape(-1, counts.shape[-1]),
         "moe_dropped": jnp.sum(per_period["dropped"]),
+        "moe_rows": jnp.sum(per_period["rows"]),
     }
 
 

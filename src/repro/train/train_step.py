@@ -34,8 +34,9 @@ from repro.sharding import partition
 
 
 #: the loss metrics a step returns besides the loss: per MoE layer and held
-#: expert the tokens routed to it, and the held assignments not computed
-MOE_COUNTERS = ("moe_counts", "moe_dropped")
+#: expert the tokens routed to it, the held assignments not computed, and
+#: the rows the expert layers' dispatches ran
+MOE_COUNTERS = ("moe_counts", "moe_dropped", "moe_rows")
 
 
 def _microbatch_loss(params, mb, cfg: ArchConfig, use_kernel: bool):

@@ -36,8 +36,9 @@ put by ``train_batch_specs``, and the step is traced under
 ``jax.set_mesh`` so the expert-parallel MoE path sees the mesh. Without
 one, everything stays on the default device. For a model with MoE layers
 the trainer counts routing (``moe_routed_tokens``, ``moe_expert_load_max``,
-``moe_dropped_tokens``) from the counts its step returns with the loss;
-each ``train.loss_sync`` carries their values as of its start.
+``moe_dropped_tokens``, ``moe_dispatch_rows``) from the counts its step
+returns with the loss; each ``train.loss_sync`` carries their values as of
+its start.
 """
 from __future__ import annotations
 
@@ -109,10 +110,12 @@ class FalconTrainer:
     param_shardings: Any = field(init=False, default=None)
     #: routing counters (models with MoE layers): tokens routed to held
     #: experts, the sum over steps of the busiest (layer, expert)'s tokens,
-    #: and held assignments left uncomputed
+    #: held assignments left uncomputed, and the rows the dispatches ran
+    #: (row use is moe_routed_tokens / moe_dispatch_rows)
     moe_routed_tokens: int = field(init=False, default=0)
     moe_expert_load_max: int = field(init=False, default=0)
     moe_dropped_tokens: int = field(init=False, default=0)
+    moe_dispatch_rows: int = field(init=False, default=0)
 
     def __post_init__(self) -> None:
         if self.mesh is None:
@@ -174,16 +177,19 @@ class FalconTrainer:
     def _moe_counters(self) -> dict:
         return {"moe_routed_tokens": self.moe_routed_tokens,
                 "moe_expert_load_max": self.moe_expert_load_max,
-                "moe_dropped_tokens": self.moe_dropped_tokens}
+                "moe_dropped_tokens": self.moe_dropped_tokens,
+                "moe_dispatch_rows": self.moe_dispatch_rows}
 
     def _read_loss_and_routing(self, metrics: dict) -> float:
         """The loss and the step's routing counts, in one read."""
-        loss, counts, dropped = jax.device_get(
-            (metrics["loss"], metrics["moe_counts"], metrics["moe_dropped"])
-        )
+        loss, counts, dropped, rows = jax.device_get((
+            metrics["loss"], metrics["moe_counts"], metrics["moe_dropped"],
+            metrics["moe_rows"],
+        ))
         self.moe_routed_tokens += int(counts.sum())
         self.moe_expert_load_max += int(counts.max())
         self.moe_dropped_tokens += int(dropped)
+        self.moe_dispatch_rows += int(rows)
         return float(loss)
 
     @property

@@ -152,7 +152,13 @@ def test_padding_rows_of_the_grouped_product_are_never_read(monkeypatch):
         return jax.jit(jax.value_and_grad(lambda p: model_lib.loss_fn(
             p, {"tokens": toks, "labels": labels}, cfg, remat=False), has_aux=True))
 
-    (want, _), want_g = loss_and_grad()(params)
+    (want, metrics), want_g = loss_and_grad()(params)
+    # 64 tokens a layer, top-3 of which 4 experts are held: rungs of 192
+    # and 128 rows, and each layer's ~64 held assignments take 128, so
+    # poisoned rows sit inside the smaller dispatch too
+    full = B * S * cfg.top_k
+    assert moe.dispatch_rungs(B * S, cfg.top_k, cfg.held_experts, cfg.num_experts) == (full, 128)
+    assert int(metrics["moe_rows"]) < cfg.num_layers * full
     monkeypatch.setattr(jax.lax, "ragged_dot", ragged_dot)
     (got, _), got_g = loss_and_grad()(params)
     assert float(got) == float(want)
@@ -181,6 +187,128 @@ def test_dropless_when_every_token_picks_one_expert():
                                rtol=1e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("t, k, e_local, experts, held, rungs, rows", [
+    # the hybrid cell's chip: 8,192 tokens, top-10, 3 of 72 experts
+    (8192, 10, 3, 72, 3413, (24576, 12288, 6144), 6144),
+    (8192, 10, 3, 72, 6145, (24576, 12288, 6144), 12288),
+    (8192, 10, 3, 72, 24576, (24576, 12288, 6144), 24576),
+    # every assignment held (the meshless olmoe, mixtral): one rung
+    (8192, 8, 64, 64, 65536, (65536,), 65536),
+    (4096, 2, 8, 8, 0, (8192,), 8192),
+    # halves round up to 128 rows, and stop at the expected load
+    (1000, 2, 2, 16, 250, (2000, 1024, 512, 256), 256),
+    (1000, 2, 2, 16, 257, (2000, 1024, 512, 256), 512),
+    # a decode step: below 128 rows every half rounds to the bound
+    (4, 2, 2, 16, 3, (8,), 8),
+])
+def test_dispatch_takes_the_smallest_rung_that_holds_the_held_rows(
+        t, k, e_local, experts, held, rungs, rows):
+    assert moe.dispatch_rungs(t, k, e_local, experts) == rungs
+    assert rungs[int(moe.rung_index(rungs, jnp.int32(held)))] == rows
+
+
+def test_every_expert_held_runs_one_dispatch_without_a_switch():
+    cfg = small(experts_held=12, expert_offset=0)
+    p = jax.tree.map(lambda a: a[0], _moe_params(cfg))
+    x = _layer_input(cfg)
+    jaxpr = str(jax.make_jaxpr(jax.grad(
+        lambda p: jnp.sum(moe.apply_moe(p, x, cfg)[0])))(p))
+    assert " cond[" not in jaxpr and "custom_vjp" not in jaxpr
+    _, _, st = moe.apply_moe(p, x, cfg)
+    assert int(st["rows"]) == B * S * cfg.top_k
+
+
+EP_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import json, sys
+import jax, jax.numpy as jnp, numpy as np
+sys.path[:0] = [os.environ["TEST_DIR"], os.environ["REPO_DIR"]]
+from test_granite_hybrid import small, weights
+from chipbench.reference import granite_hybrid as ref
+from repro.launch.mesh import make_host_mesh
+from repro.models import moe
+from repro.models.reference_granite_hybrid import config_dict
+
+# 480 tokens; experts 4-7 held, one a chip: rungs of 480, 256, 128 rows
+cfg = small()
+t, d = 480, cfg.d_model
+p = jax.tree.map(lambda a: a[0], weights(cfg, 2)["blocks"]["sub0"]["moe"])
+ct = jax.random.normal(jax.random.key(5), (1, t, d), jnp.float32)
+cast = lambda a: a.astype(jnp.float32)
+mm = lambda a, w: jnp.dot(a, w.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST)
+rcfg = config_dict(cfg)
+balanced = np.zeros((cfg.num_experts, cfg.num_experts), np.float32)
+for i in range(cfg.num_experts):  # token of feature i: experts i, i+1, i+2
+    for m in range(cfg.top_k):
+        balanced[i, (i + m) % cfg.num_experts] = 1.0 - 0.1 * m
+one_hot = jax.nn.one_hot(np.arange(t) % cfg.num_experts, d) * 10.0
+cases = {
+    # each expert in the top-3 of exactly a quarter of the tokens
+    "balanced": (dict(p, router=jnp.zeros_like(p["router"]).at[:cfg.num_experts].set(balanced)),
+                 one_hot + 0.01 * jax.random.normal(jax.random.key(3), (t, d))),
+    # expert 5 (held by chip 1) in every token's top-3
+    "one_expert": (dict(p, router=p["router"].at[0, :].set(0.0).at[0, 5].set(10.0)),
+                   jax.random.normal(jax.random.key(3), (t, d)).at[:, 0].set(100.0)),
+}
+mesh = make_host_mesh(1, 4)
+out = {}
+for name, (q, x) in cases.items():
+    x = x[None].astype(jnp.float32)
+    def prog(q, x):
+        y, _, st = moe.apply_moe(q, x, cfg)
+        return jnp.sum(y * ct), (y, st)
+    def plain(q, x):
+        y, _ = ref._moe(q, x[0], rcfg, cast, mm)
+        return jnp.sum(y * ct[0]), y
+    with jax.set_mesh(mesh):
+        (_, (y, st)), g = jax.jit(jax.value_and_grad(prog, has_aux=True))(q, x)
+    (_, want), want_g = jax.jit(jax.value_and_grad(plain, has_aux=True))(q, x)
+    rungs = moe.dispatch_rungs(t, cfg.top_k, 1, cfg.num_experts)
+    counts = np.asarray(st["counts"])
+    expect = sum(rungs[int(moe.rung_index(rungs, c))] for c in counts)
+    np.testing.assert_allclose(np.asarray(y[0]), np.asarray(want), rtol=1e-4, atol=1e-5)
+    leaves = ("wi_gate", "wi_up", "wo", "router")
+    median = np.median([np.linalg.norm(np.asarray(want_g[k], np.float64)) for k in leaves])
+    gaps = {k: float(np.linalg.norm(np.asarray(g[k], np.float64) - np.asarray(want_g[k]))
+                     / max(np.linalg.norm(np.asarray(want_g[k], np.float64)), median))
+            for k in leaves}
+    out[name] = {"gaps": gaps, "counts": counts.tolist(), "rows": int(st["rows"]), "expect": expect,
+                 "dropped": int(st["dropped"]), "rungs": list(rungs)}
+print("RESULT " + json.dumps(out))
+"""
+
+
+def test_expert_parallel_dispatch_takes_its_rung_and_matches_the_reference():
+    env = dict(os.environ)
+    here = os.path.dirname(os.path.abspath(__file__))
+    repo = os.path.dirname(here)
+    env["PYTHONPATH"] = os.path.join(repo, "src") + os.pathsep + env.get("PYTHONPATH", "")
+    env["TEST_DIR"], env["REPO_DIR"] = here, repo
+    env.pop("XLA_FLAGS", None)
+    done = subprocess.run([sys.executable, "-c", EP_SCRIPT], env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert done.returncode == 0, done.stderr[-3000:]
+    import json
+
+    line = [ln for ln in done.stdout.splitlines() if ln.startswith("RESULT ")][-1]
+    got = json.loads(line[len("RESULT "):])
+    bal, one = got["balanced"], got["one_expert"]
+    assert bal["rungs"] == [480, 256, 128]
+    # 480 tokens x top-3 over 12 experts: 120 on each chip's one expert
+    assert bal["counts"] == [120] * 4 and bal["rows"] == 4 * 128
+    # chip 1 holds expert 5, in every token's top-3: the top rung
+    assert one["counts"][1] == 480 and one["rows"] == one["expect"] >= 480 + 3 * 128
+    assert bal["dropped"] == one["dropped"] == 0
+    for case in (bal, one):
+        # each leaf's gradient against the reference's, over the larger of
+        # its norm and the median leaf's (as the benchmark compares them)
+        assert max(case["gaps"][k] for k in ("wi_gate", "wi_up", "wo")) <= 1e-4, case["gaps"]
+        # the expert-parallel path's router gradient sits further off (5e-4
+        # here in float32), with a dispatch of fixed rows too
+        assert case["gaps"]["router"] <= 2e-3, case["gaps"]
+
+
 MESH_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
@@ -206,7 +334,7 @@ for name, mesh in (("one", None), ("mesh", make_host_mesh(1, 4))):
         "mu": [float(np.linalg.norm(np.asarray(m, np.float64)))
                for m in jax.tree.leaves(t.opt_state.mu)],
         "routed": t.moe_routed_tokens, "load_max": t.moe_expert_load_max,
-        "dropped": t.moe_dropped_tokens,
+        "dropped": t.moe_dropped_tokens, "rows": t.moe_dispatch_rows,
         "expert_sharding": str(getattr(
             t.params["blocks"]["sub0"]["moe"]["wi_gate"].sharding, "spec", None)),
     }
@@ -235,6 +363,11 @@ def test_four_device_mesh_step_matches_the_one_device_step():
     for k in ("routed", "load_max", "dropped"):
         assert mesh[k] == one[k], k
     assert one["dropped"] == 0 and one["routed"] > 0
+    # 2 steps x 2 slots x 10 layers of 64 tokens: on the mesh each chip's
+    # one expert has a single rung of 64 rows; on one device the four held
+    # experts' rungs are 192 and 128 rows
+    assert mesh["rows"] == 2 * 2 * 10 * 4 * 64
+    assert 2 * 2 * 10 * 128 <= one["rows"] <= 2 * 2 * 10 * 192
 
 
 def test_moe_trainer_counts_routing_on_one_device():
@@ -246,6 +379,8 @@ def test_moe_trainer_counts_routing_on_one_device():
     assert 0 < t.moe_routed_tokens <= 4 * S * cfg.top_k * 10
     assert t.moe_expert_load_max >= t.moe_routed_tokens / (10 * 4)
     assert t.moe_dropped_tokens == 0
+    # 2 slots x 10 layers, each dispatch 128 or 192 rows
+    assert 2 * 10 * 128 <= t.moe_dispatch_rows <= 2 * 10 * 192
 
 
 def test_dense_step_without_a_mesh_is_the_plain_jitted_step():
