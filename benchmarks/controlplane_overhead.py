@@ -11,14 +11,6 @@ registered jobs — plus the same loop with the observability span tracer
 attached (``per_tick_traced_us`` / ``trace_overhead_pct``): the tracing
 contract is <5 % per-tick overhead when on and zero extra allocations on
 the hot path when off, asserted here in smoke mode.
-
-Also measured: the fused multi-cohort screen
-(``fleet_kwargs={"fused": True}`` — every warmed cohort advances in ONE
-BatchedBOCD launch per tick instead of one launch per cohort;
-``per_tick_fused_us`` / ``fused_delta_pct``). The fused frontier is
-bitwise-equivalent to the per-cohort default (pinned by
-tests/test_fleet.py), so the delta is pure launch-overhead accounting;
-each row asserts the fused loop's flag stream matches the default's.
 """
 from __future__ import annotations
 
@@ -40,15 +32,11 @@ TRACE_BUDGET_PCT = 5.0
 REPEATS = 5
 
 
-def _tick_loop(
-    n_jobs: int, n_iters: int, seed: int, tracer=None, fused: bool = False
-) -> tuple:
+def _tick_loop(n_jobs: int, n_iters: int, seed: int, tracer=None) -> tuple:
     traces = sample_campaign(
         seed=seed, n_jobs=n_jobs, failslow_rate=0.4, n_iters=n_iters
     )
-    plane = ControlPlane(
-        tracer=tracer, fleet_kwargs={"fused": True} if fused else None
-    )
+    plane = ControlPlane(tracer=tracer)
     adapters = []
     for i, trace in enumerate(traces):
         adapter = TraceReplayAdapter(trace)
@@ -76,8 +64,7 @@ def _measure(n_jobs: int, n_iters: int, seed: int = 0) -> dict:
     # true overhead from above). One untimed warmup round first.
     _tick_loop(n_jobs, min(n_iters, 160), seed)
     plane = traces = ticks = None
-    plane_f = None
-    base = traced = fused = float("inf")
+    base = traced = float("inf")
     pair_pcts: list[float] = []
     for rep in range(REPEATS):
         if rep % 2 == 0:
@@ -85,30 +72,15 @@ def _measure(n_jobs: int, n_iters: int, seed: int = 0) -> dict:
             _, _, _, elapsed_t = _tick_loop(
                 n_jobs, n_iters, seed, tracer=SpanTracer()
             )
-            plane_f, _, _, elapsed_f = _tick_loop(
-                n_jobs, n_iters, seed, fused=True
-            )
         else:
-            plane_f, _, _, elapsed_f = _tick_loop(
-                n_jobs, n_iters, seed, fused=True
-            )
             _, _, _, elapsed_t = _tick_loop(
                 n_jobs, n_iters, seed, tracer=SpanTracer()
             )
             plane, traces, ticks, elapsed = _tick_loop(n_jobs, n_iters, seed)
         base = min(base, elapsed)
         traced = min(traced, elapsed_t)
-        fused = min(fused, elapsed_f)
         pair_pcts.append(100.0 * (elapsed_t - elapsed) / elapsed)
     pair_pcts.sort()
-
-    # The fused screen must be behaviorally indistinguishable from the
-    # per-cohort default — same typed event stream, launch count aside.
-    ev, ev_f = list(plane.events), list(plane_f.events)
-    assert len(ev) == len(ev_f) and all(
-        type(a) is type(b) and a.__dict__ == b.__dict__
-        for a, b in zip(ev, ev_f)
-    ), f"fused screen event stream diverged at n_jobs={n_jobs}"
 
     flags = sum(isinstance(e, Flag) for e in plane.events)
     diagnosed = {
@@ -125,8 +97,6 @@ def _measure(n_jobs: int, n_iters: int, seed: int = 0) -> dict:
         "per_tick_traced_us": round(1e6 * traced / ticks, 1),
         "trace_overhead_pct": round(pair_pcts[len(pair_pcts) // 2], 2),
         "trace_overhead_best_pct": round(pair_pcts[0], 2),
-        "per_tick_fused_us": round(1e6 * fused / ticks, 1),
-        "fused_delta_pct": round(100.0 * (fused - base) / base, 2),
         "flags": flags,
         "jobs_diagnosed": len(diagnosed),
         "jobs_with_failslow": true_failslow,

@@ -1379,12 +1379,10 @@ class PallasReduction:
         return res
 
 
-#: registry the equivalence tests enumerate; "numpy" mirrors the screening
-#: registry's alias for the default non-kernel path
+#: registry the equivalence tests enumerate
 REDUCTION_BACKENDS: dict[str, type] = {
     "reference": ReferenceReduction,
     "vectorized": VectorizedReduction,
-    "numpy": VectorizedReduction,
     "pallas": PallasReduction,
 }
 
@@ -1423,7 +1421,7 @@ def resolve_reduction_backend(spec, job: JobSpec):
     ``None``/``"auto"`` picks ``pallas`` only on a TPU *and* for a full
     hybrid job (:func:`fits_pallas_reduction`); every other case runs the
     inline path, and :attr:`TrainingSimulator.reduction_name` reports
-    which. ``"vectorized"``/``"numpy"`` are the inline path. An explicit
+    which. ``"vectorized"`` is the inline path. An explicit
     ``pallas`` (name or instance) for a degenerate job raises
     ``ValueError`` instead of quietly running something else.
     """
@@ -1433,7 +1431,7 @@ def resolve_reduction_backend(spec, job: JobSpec):
         fits = fits_pallas_reduction(job) and pallas_compiled()
         return PallasReduction() if fits else None
     if isinstance(spec, str):
-        if spec in ("vectorized", "numpy"):
+        if spec == "vectorized":
             return None
         spec = select_reduction_backend(spec)
     elif not hasattr(spec, "iteration_time"):

@@ -31,7 +31,6 @@ Two implementations share the recursion:
 from __future__ import annotations
 
 import math
-import warnings
 from dataclasses import dataclass, field
 from typing import Protocol, runtime_checkable
 
@@ -485,621 +484,6 @@ class BatchedBOCD:
         self._t = snap["t"]
 
 
-@dataclass(eq=False)
-class _MultiGroup:
-    """One cohort's slice of a :class:`MultiBOCD` frontier.
-
-    ``cols`` are this group's column indices into the shared ``(K, B)``
-    arrays; the group's live hypothesis rows are the prefix ``0..k-1`` (cells
-    below are ``-inf``-posterior voids). ``rl`` is the group's row-constant
-    run length, exactly as a standalone :class:`BatchedBOCD` would hold it;
-    the row-constant ``kappa``/``alpha`` statistics are *derived* —
-    ``kappa0 + (rl+1)`` steps of +1.0 / ``alpha0 + (rl+1)`` steps of +0.5 —
-    and read from the owner's shared age ladders.
-
-    ``cols`` is always a contiguous ascending range ``c0..c1-1``: absorb
-    appends a fresh ``arange`` block, and column removal deletes columns
-    from inside a group's own range while shifting every other group's
-    block uniformly, which preserves contiguity. The range bounds are
-    cached so the per-tick loops can slice (views) instead of fancy-index
-    (copies).
-    """
-
-    cols: np.ndarray
-    hazard: float
-    cap: int | None
-    k: int
-    rl: np.ndarray
-    #: updates this group has absorbed (the standalone batch's ``_t`` —
-    #: warm replay plus fused ticks; export hands it back verbatim)
-    t: int = 0
-    c0: int = 0
-    c1: int = 0
-
-    def refresh_range(self) -> None:
-        self.c0 = int(self.cols[0])
-        self.c1 = int(self.cols[-1]) + 1
-        if self.c1 - self.c0 != self.cols.size:
-            raise AssertionError(
-                "MultiBOCD group columns are no longer contiguous"
-            )
-
-
-class MultiBOCD:
-    """Several independent :class:`BatchedBOCD` groups advanced in ONE fused
-    per-tick pass (the multi-cohort screen, ROADMAP item 4 residual).
-
-    :class:`repro.core.detector.FleetDetect` keeps one batch per cohort, so a
-    churny fleet pays the fixed cost of every small numpy op once *per
-    cohort* per tick. This class holds all cohorts in shared ``(K, B)`` cell
-    arrays (``K`` = largest group frontier, ``B`` = total streams) and runs
-    the expensive elementwise chains — the Student-t log-predictive, the
-    posterior normalization, the Normal-Gamma statistics update — once per
-    tick across every group.
-
-    Bit-exactness contract: each group's posterior is **bit-identical** to
-    what its standalone :class:`BatchedBOCD` would hold. This relies on
-    three properties, each covered by the equivalence tests:
-
-    * elementwise chains are applied per cell in the exact same operation
-      order as :func:`_student_t_logpdf_rows` / :meth:`BatchedBOCD.update`,
-      so every cell sees the same float sequence;
-    * numpy's axis-0 reductions accumulate row-sequentially for matrices
-      with ``>= 2`` columns, so void rows (``exp(-inf) == 0.0``) and column
-      sub-slices reduce bit-identically to the per-cohort operands;
-    * single-column operands take numpy's 1-D pairwise-summation path, which
-      *does* reassociate under padding — so any reduction whose per-cohort
-      equivalent ran on one column is recomputed on a contiguous
-      ``(k, 1)`` copy of exactly the per-cohort shape.
-
-    Groups enter via :meth:`absorb` (adopting a warmed standalone batch) and
-    are driven through :class:`MultiGroupHandle`, which implements the
-    per-cohort :class:`ScreeningBackend` surface minus ``update``.
-    """
-
-    def __init__(self) -> None:
-        self.kappa0 = 1.0
-        self.alpha0 = 1.0
-        self.beta0 = 1.0
-        self.truncation = 1e-6
-        self.cp_threshold = DEFAULT_CP_THRESHOLD
-        self._groups: list[_MultiGroup] = []
-        self._log_r = np.zeros((0, 0))
-        self._mu = np.zeros((0, 0))
-        self._beta = np.zeros((0, 0))
-        self._mu0 = np.zeros(0)
-        #: per-cell hypothesis age: the number of +1.0 kappa / +0.5 alpha
-        #: prior-update steps the cell's run-length hypothesis has absorbed
-        #: (live rows: rl + 1, row-constant per group; void cells just keep
-        #: counting — their posterior is -inf so the values are never read).
-        #: Ages index the shared ladders below, turning the per-group
-        #: row-to-cell scatter of kappa/alpha/const into three gathers.
-        self._age = np.zeros((0, 0), dtype=np.int64)
-        self._age_hi = 0
-        self._kap_lad = np.array([self.kappa0])
-        self._alp_lad = np.array([self.alpha0])
-        self._const_lad = _gammaln((2.0 * self._alp_lad + 1.0) / 2.0) - \
-            _gammaln(2.0 * self._alp_lad / 2.0)
-        #: per-column log hazard / log(1-hazard), maintained on membership
-        #: and retune instead of rebuilt every tick
-        self._log_h = np.zeros(0)
-        self._log_1mh = np.zeros(0)
-        self._t = 0
-
-    def _ensure_ladder(self, hi: int) -> None:
-        """Extend the shared kappa/alpha/const ladders to cover age ``hi``.
-
-        Values are chained incrementally (``+1.0`` / ``+0.5`` per step from
-        the prior), the exact accumulation a per-tick row update performs,
-        so a ladder read is bit-identical to the incrementally maintained
-        row statistic it replaces.
-        """
-        n0 = self._kap_lad.size
-        if n0 > hi:
-            return
-        kl = np.empty(hi + 1)
-        al = np.empty(hi + 1)
-        kl[:n0] = self._kap_lad
-        al[:n0] = self._alp_lad
-        for j in range(n0, hi + 1):
-            kl[j] = kl[j - 1] + 1.0
-            al[j] = al[j - 1] + 0.5
-        cl = np.empty(hi + 1)
-        cl[:n0] = self._const_lad
-        df = 2.0 * al[n0:]
-        cl[n0:] = _gammaln((df + 1.0) / 2.0) - _gammaln(df / 2.0)
-        self._kap_lad, self._alp_lad, self._const_lad = kl, al, cl
-
-    @property
-    def n_series(self) -> int:
-        return int(self._mu0.size)
-
-    @property
-    def n_groups(self) -> int:
-        return len(self._groups)
-
-    # -- membership ----------------------------------------------------
-    def absorb(self, batch: BatchedBOCD) -> "MultiGroupHandle":
-        """Adopt a warmed standalone batch as a new group; returns its
-        handle. The batch's posterior state is copied verbatim — its columns
-        append to the shared arrays, its rows land in the group's prefix."""
-        if not isinstance(batch, BatchedBOCD):
-            raise TypeError(f"MultiBOCD absorbs BatchedBOCD, got {type(batch)!r}")
-        if self._groups:
-            for name in ("kappa0", "alpha0", "beta0", "truncation",
-                         "cp_threshold"):
-                if getattr(batch, name) != getattr(self, name):
-                    raise ValueError(
-                        f"group {name}={getattr(batch, name)!r} differs from "
-                        f"the shared frontier's {getattr(self, name)!r}"
-                    )
-        else:
-            self.kappa0 = batch.kappa0
-            self.alpha0 = batch.alpha0
-            self.beta0 = batch.beta0
-            self.truncation = batch.truncation
-            self.cp_threshold = batch.cp_threshold
-            self._kap_lad = np.array([self.kappa0])
-            self._alp_lad = np.array([self.alpha0])
-            self._const_lad = _gammaln((2.0 * self._alp_lad + 1.0) / 2.0) - \
-                _gammaln(2.0 * self._alp_lad / 2.0)
-        kb, bb = batch._log_r.shape
-        b0 = self.n_series
-        k_new = max(self._log_r.shape[0], kb)
-
-        def _pad(a: np.ndarray, fill: float) -> np.ndarray:
-            if a.shape[0] == k_new:
-                return a
-            out = np.full((k_new, a.shape[1]), fill)
-            out[: a.shape[0]] = a
-            return out
-
-        self._log_r = np.hstack(
-            [_pad(self._log_r, -np.inf), _pad(batch._log_r, -np.inf)]
-        )
-        # Void-cell stats only need to stay finite (their posterior is -inf
-        # forever); pad with the prior.
-        self._mu = np.hstack([_pad(self._mu, 0.0), _pad(batch._mu, 0.0)])
-        self._beta = np.hstack(
-            [_pad(self._beta, self.beta0), _pad(batch._beta, self.beta0)]
-        )
-        self._mu0 = np.concatenate([self._mu0, batch._mu0])
-        # Row age = number of +1.0 kappa updates absorbed since the prior:
-        # recovered exactly from the batch's kappa row (small-integer float
-        # arithmetic — the original prior-seeded row has age == rl, rows
-        # born by an update have age == rl + 1, so rl alone is ambiguous).
-        ages = np.zeros((k_new, bb), dtype=np.int64)
-        ages[:kb] = np.rint(
-            batch._kappa_row - self.kappa0
-        ).astype(np.int64)[:, None]
-        age_pad = self._age
-        if age_pad.shape[0] != k_new:
-            grown = np.zeros((k_new, age_pad.shape[1]), dtype=np.int64)
-            grown[: age_pad.shape[0]] = age_pad
-            age_pad = grown
-        self._age = np.hstack([age_pad, ages])
-        hi = int(ages[:kb].max()) if kb else 0
-        self._age_hi = max(self._age_hi, hi)
-        self._ensure_ladder(self._age_hi)
-        self._log_h = np.concatenate(
-            [self._log_h, np.full(bb, math.log(batch.hazard))]
-        )
-        self._log_1mh = np.concatenate(
-            [self._log_1mh, np.full(bb, math.log1p(-batch.hazard))]
-        )
-        grp = _MultiGroup(
-            cols=np.arange(b0, b0 + bb, dtype=np.int64),
-            hazard=batch.hazard,
-            cap=batch.max_hypotheses,
-            k=kb,
-            rl=batch._rl.copy(),
-            t=batch._t,
-        )
-        grp.refresh_range()
-        self._groups.append(grp)
-        return MultiGroupHandle(self, grp)
-
-    def export(self, grp: _MultiGroup) -> BatchedBOCD:
-        """Materialize one group back into a standalone batch (bit-equal
-        state; used for consolidation rebuilds and equivalence tests)."""
-        out = BatchedBOCD(
-            grp.cols.size,
-            hazard=grp.hazard,
-            mu0=self._mu0[grp.cols],
-            kappa0=self.kappa0,
-            alpha0=self.alpha0,
-            beta0=self.beta0,
-            cp_threshold=self.cp_threshold,
-            truncation=self.truncation,
-            max_hypotheses=grp.cap,
-        )
-        # ascontiguousarray: the shared arrays are C-ordered but wider than
-        # the group, and numpy's reduction path (row-sequential vs 1-D
-        # pairwise) depends on layout — a standalone batch's arrays are
-        # compact C-ordered.
-        out._log_r = np.ascontiguousarray(self._log_r[: grp.k, grp.c0:grp.c1])
-        out._mu = np.ascontiguousarray(self._mu[: grp.k, grp.c0:grp.c1])
-        out._beta = np.ascontiguousarray(self._beta[: grp.k, grp.c0:grp.c1])
-        # Row-constant kappa/alpha reconstruct from the age ladders (the
-        # ladder is the same +1.0/+0.5 accumulation chain, so the values
-        # are bit-identical to an incrementally maintained row).
-        ages = self._age[: grp.k, grp.c0]
-        out._kappa_row = self._kap_lad[ages]
-        out._alpha_row = self._alp_lad[ages]
-        out._rl = grp.rl.copy()
-        out._t = grp.t
-        return out
-
-    def take_group_columns(self, grp: _MultiGroup, idx: np.ndarray) -> None:
-        """Per-group :meth:`BatchedBOCD.take_columns`: keep the group's
-        local columns ``idx``, drop the rest from the shared arrays, then
-        compact the group's rows exactly like the standalone would."""
-        idx = np.asarray(idx, dtype=np.int64)
-        kept = grp.cols[idx]
-        removed = np.setdiff1d(grp.cols, kept)
-        if removed.size:
-            mask = np.ones(self.n_series, dtype=bool)
-            mask[removed] = False
-            self._log_r = self._log_r[:, mask]
-            self._mu = self._mu[:, mask]
-            self._beta = self._beta[:, mask]
-            self._age = self._age[:, mask]
-            self._mu0 = self._mu0[mask]
-            self._log_h = self._log_h[mask]
-            self._log_1mh = self._log_1mh[mask]
-            remap = np.cumsum(mask) - 1
-            for g in self._groups:
-                if g is grp:
-                    continue
-                g.cols = remap[g.cols]
-                g.refresh_range()
-            grp.cols = remap[kept]
-        if grp.cols.size == 0:
-            self._groups.remove(grp)
-            self._shrink()
-            return
-        grp.refresh_range()
-        sub = self._log_r[: grp.k, grp.c0:grp.c1]
-        alive = np.isfinite(sub).any(axis=1)
-        if alive.size:
-            alive[0] = True
-        if not alive.all():
-            self._pack_group(grp, np.flatnonzero(alive), grp.k)
-        self._shrink()
-
-    def retune_group(
-        self,
-        grp: _MultiGroup,
-        hazard: float | None = None,
-        max_hypotheses: int | None = None,
-    ) -> None:
-        if hazard is not None:
-            grp.hazard = hazard
-            self._log_h[grp.c0:grp.c1] = math.log(hazard)
-            self._log_1mh[grp.c0:grp.c1] = math.log1p(-hazard)
-        if max_hypotheses is not None:
-            grp.cap = max_hypotheses
-
-    # -- fused tick ----------------------------------------------------
-    def update(self, x: np.ndarray) -> None:
-        """Feed one observation per stream; advances every group at once."""
-        b = self.n_series
-        x = np.asarray(x, dtype=np.float64)
-        if x.shape != (b,):
-            raise ValueError(f"expected shape ({b},), got {x.shape}")
-        groups = self._groups
-        if not groups:
-            return
-        k = self._log_r.shape[0]
-        # Per-cell kappa/alpha/const via one ladder gather each (ages are
-        # row-constant per group on live cells; void cells keep counting but
-        # their posterior is -inf, so only finiteness matters there). The
-        # (k+1)-tall age array built here doubles as the post-update ages.
-        self._age_hi += 1
-        self._ensure_ladder(self._age_hi)
-        age_all = np.empty((k + 1, b), dtype=np.int64)
-        age_all[0] = 0
-        age_all[1:] = self._age
-        kappa_all = self._kap_lad[age_all]
-        kappa = kappa_all[1:]
-        alpha = self._alp_lad[self._age]
-        const = self._const_lad[self._age]
-
-        # Student-t log-predictive: the exact _student_t_logpdf_rows chain,
-        # with the row-constant factors materialized per cell (same floats,
-        # same op order -> bit-identical).
-        df = 2.0 * alpha
-        scale2 = self._beta * (kappa + 1.0)
-        scale2 /= alpha * kappa
-        z2 = x - self._mu
-        np.multiply(z2, z2, out=z2)
-        z2 /= scale2
-        z2 /= df
-        np.log1p(z2, out=z2)
-        z2 *= (df + 1.0) / 2.0
-        scale2 *= np.pi * df
-        np.log(scale2, out=scale2)
-        scale2 *= 0.5
-        np.subtract(const, scale2, out=scale2)
-        scale2 -= z2
-        log_growth = scale2
-        log_growth += self._log_r
-        log_growth += self._log_1mh
-        log_cp = _student_t_logpdf(
-            x, self._mu0, np.float64(self.kappa0), np.float64(self.alpha0),
-            np.float64(self.beta0),
-        )
-        log_cp = log_cp + self._log_h
-
-        new_log_r = np.empty((k + 1, b))
-        new_log_r[0] = log_cp
-        new_log_r[1:] = log_growth
-        norm = _logsumexp_cols(new_log_r)
-        for g in groups:
-            # Single-column groups normalize over a contiguous (k+1, 1)
-            # array in the standalone batch, which numpy reduces on the 1-D
-            # pairwise path — recompute on the per-cohort shape.
-            if g.c1 - g.c0 == 1:
-                norm[g.c0] = _logsumexp_cols(
-                    np.ascontiguousarray(new_log_r[: g.k + 1, g.c0:g.c1])
-                )[0]
-        new_log_r -= norm
-
-        mu_all = np.empty((k + 1, b))
-        mu_all[0] = self._mu0
-        mu_all[1:] = self._mu
-        beta_all = np.empty((k + 1, b))
-        beta_all[0] = self.beta0
-        beta_all[1:] = self._beta
-        denom = kappa_all + 1.0
-        upd = x - mu_all
-        np.multiply(upd, upd, out=upd)
-        upd *= 0.5 * kappa_all
-        upd /= denom
-        beta_all += upd
-        mu_all *= kappa_all
-        mu_all += x
-        mu_all /= denom
-        age_all += 1
-        self._age = age_all
-        for g in groups:
-            rl = np.empty(g.k + 1, dtype=np.int64)
-            rl[0] = 0
-            rl[1:] = g.rl
-            rl[1:] += 1
-            g.rl = rl
-            g.k += 1
-            g.t += 1
-        self._t += 1
-
-        # Per-column truncation (global: void cells are excluded by the
-        # isfinite mask) ...
-        dead = new_log_r <= math.log(self.truncation)
-        dead[0] = False
-        dead &= np.isfinite(new_log_r)
-        if dead.any():
-            new_log_r[dead] = -np.inf
-        # ... the shared frontier cap, per group (contiguous column ranges:
-        # slices are views, so the kill writes through) ...
-        for g in groups:
-            cap = g.cap
-            k1 = g.k
-            if cap is None or k1 <= cap:
-                continue
-            sub = new_log_r[:k1, g.c0:g.c1]
-            strength = np.max(sub, axis=1)
-            order = np.argsort(strength[1:], kind="stable")  # ascending
-            kill = np.zeros((k1, g.c1 - g.c0), dtype=bool)
-            kill[order[: k1 - cap] + 1] = True
-            kill &= np.isfinite(sub)
-            if kill.any():
-                sub[kill] = -np.inf
-                dead[:k1, g.c0:g.c1] |= kill
-        # ... and one renormalize + compact pass per affected group, on
-        # operands shaped exactly like the standalone batch's.
-        for g in groups:
-            k1 = g.k
-            gdead = dead[:k1, g.c0:g.c1]
-            if not gdead.any():
-                continue
-            cols_aff = gdead.any(axis=0)
-            # Memory layout decides numpy's reduction path, so each branch
-            # mirrors the standalone operand's layout exactly: the >0.5
-            # branch renormalizes the full C-ordered posterior, the other
-            # renormalizes an F-ordered axis-1 fancy copy.
-            if cols_aff.mean() > 0.5:
-                operand = np.ascontiguousarray(new_log_r[:k1, g.c0:g.c1])
-                gnorm = _logsumexp_cols(operand)
-                new_log_r[:k1, g.c0:g.c1] -= gnorm
-            else:
-                sel = g.cols[cols_aff]
-                operand = new_log_r[:k1][:, sel]
-                gnorm = _logsumexp_cols(operand)
-                new_log_r[np.arange(k1)[:, None], sel[None, :]] -= gnorm
-            alive = np.isfinite(new_log_r[:k1, g.c0:g.c1]).any(axis=1)
-            alive[0] = True
-            if not alive.all():
-                self._pack_group(
-                    grp=g, rows=np.flatnonzero(alive), k1=k1,
-                    log_r=new_log_r, mu=mu_all, beta=beta_all,
-                )
-        k_max = max(g.k for g in groups)
-        self._log_r = new_log_r[:k_max]
-        self._mu = mu_all[:k_max]
-        self._beta = beta_all[:k_max]
-        if self._age.shape[0] > k_max:
-            self._age = self._age[:k_max]
-
-    def _pack_group(
-        self,
-        grp: _MultiGroup,
-        rows: np.ndarray,
-        k1: int,
-        log_r: np.ndarray | None = None,
-        mu: np.ndarray | None = None,
-        beta: np.ndarray | None = None,
-    ) -> None:
-        """Compact ``grp``'s live rows to the prefix, voiding the tail."""
-        log_r = self._log_r if log_r is None else log_r
-        mu = self._mu if mu is None else mu
-        beta = self._beta if beta is None else beta
-        m = rows.size
-        c0, c1 = grp.c0, grp.c1
-        for arr in (log_r, mu, beta, self._age):
-            arr[:m, c0:c1] = arr[rows, c0:c1]
-        log_r[m:k1, c0:c1] = -np.inf
-        grp.rl = grp.rl[rows]
-        grp.k = m
-
-    def _shrink(self) -> None:
-        if not self._groups:
-            self._log_r = np.zeros((0, self.n_series))
-            self._mu = np.zeros((0, self.n_series))
-            self._beta = np.zeros((0, self.n_series))
-            self._age = np.zeros((0, self.n_series), dtype=np.int64)
-            return
-        k_max = max(g.k for g in self._groups)
-        if k_max < self._log_r.shape[0]:
-            self._log_r = self._log_r[:k_max]
-            self._mu = self._mu[:k_max]
-            self._beta = self._beta[:k_max]
-            self._age = self._age[:k_max]
-
-    # -- per-group detection statistics --------------------------------
-    def p_recent_group(self, grp: _MultiGroup, window: int = 2) -> np.ndarray:
-        j = int(np.searchsorted(grp.rl, window, side="right"))
-        if j == 0:
-            return np.zeros(grp.cols.size)
-        return np.exp(
-            _logsumexp_cols(
-                np.ascontiguousarray(self._log_r[:j, grp.c0:grp.c1])
-            )
-        )
-
-    def map_runlength_group(self, grp: _MultiGroup) -> np.ndarray:
-        return grp.rl[
-            np.argmax(self._log_r[: grp.k, grp.c0:grp.c1], axis=0)
-        ]
-
-    # -- state capture (campaign fork/restore contract) ------------------
-    def snapshot(self) -> dict:
-        """Full fused-frontier state as private copies. Group order is
-        preserved, so a caller holding per-group handles can re-associate
-        them by index after :meth:`restore`."""
-        return {
-            "params": (self.kappa0, self.alpha0, self.beta0,
-                       self.truncation, self.cp_threshold),
-            "log_r": self._log_r.copy(),
-            "mu": self._mu.copy(),
-            "beta": self._beta.copy(),
-            "mu0": self._mu0.copy(),
-            "age": self._age.copy(),
-            "age_hi": self._age_hi,
-            "t": self._t,
-            "groups": [
-                {
-                    "cols": g.cols.copy(),
-                    "hazard": g.hazard,
-                    "cap": g.cap,
-                    "k": g.k,
-                    "rl": g.rl.copy(),
-                    "t": g.t,
-                }
-                for g in self._groups
-            ],
-        }
-
-    def restore(self, snap: dict) -> None:
-        """Reinstate a :meth:`snapshot` bit-exactly. The age ladders are
-        pure functions of the priors and only ever extend, so the current
-        (possibly longer) ladders are kept. Existing group objects are
-        replaced — callers must rebind handles via ``_groups`` order."""
-        (self.kappa0, self.alpha0, self.beta0,
-         self.truncation, self.cp_threshold) = snap["params"]
-        self._log_r = snap["log_r"].copy()
-        self._mu = snap["mu"].copy()
-        self._beta = snap["beta"].copy()
-        self._mu0 = snap["mu0"].copy()
-        self._age = snap["age"].copy()
-        self._age_hi = snap["age_hi"]
-        if (self._kap_lad[0] != self.kappa0
-                or self._alp_lad[0] != self.alpha0):
-            self._kap_lad = np.array([self.kappa0])
-            self._alp_lad = np.array([self.alpha0])
-            self._const_lad = _gammaln((2.0 * self._alp_lad + 1.0) / 2.0) - \
-                _gammaln(2.0 * self._alp_lad / 2.0)
-        self._ensure_ladder(self._age_hi)
-        self._t = snap["t"]
-        self._groups = []
-        for g in snap["groups"]:
-            grp = _MultiGroup(
-                cols=g["cols"].copy(), hazard=g["hazard"], cap=g["cap"],
-                k=g["k"], rl=g["rl"].copy(), t=g["t"],
-            )
-            grp.refresh_range()
-            self._groups.append(grp)
-        b = self.n_series
-        self._log_h = np.empty(b)
-        self._log_1mh = np.empty(b)
-        for grp in self._groups:
-            self._log_h[grp.c0:grp.c1] = math.log(grp.hazard)
-            self._log_1mh[grp.c0:grp.c1] = math.log1p(-grp.hazard)
-
-
-class MultiGroupHandle:
-    """Per-cohort :class:`ScreeningBackend` facade over one
-    :class:`MultiBOCD` group — everything except ``update`` (observations
-    flow through the owner's fused :meth:`MultiBOCD.update`)."""
-
-    def __init__(self, multi: MultiBOCD, grp: _MultiGroup) -> None:
-        self.multi = multi
-        self.group = grp
-
-    @property
-    def n_series(self) -> int:
-        return int(self.group.cols.size)
-
-    @property
-    def cols(self) -> np.ndarray:
-        """This group's column indices into the fused input vector."""
-        return self.group.cols
-
-    @property
-    def hazard(self) -> float:
-        return self.group.hazard
-
-    @property
-    def max_hypotheses(self) -> int | None:
-        return self.group.cap
-
-    def update(self, x: np.ndarray) -> np.ndarray:
-        raise RuntimeError(
-            "MultiGroupHandle does not update per group; feed the fused "
-            "MultiBOCD.update once per tick"
-        )
-
-    def p_recent_change(self, window: int = 2) -> np.ndarray:
-        return self.multi.p_recent_group(self.group, window)
-
-    def map_runlength(self) -> np.ndarray:
-        return self.multi.map_runlength_group(self.group)
-
-    def take_columns(self, idx: np.ndarray) -> None:
-        self.multi.take_group_columns(self.group, idx)
-
-    def retune(
-        self,
-        hazard: float | None = None,
-        max_hypotheses: int | None = None,
-    ) -> None:
-        self.multi.retune_group(
-            self.group, hazard=hazard, max_hypotheses=max_hypotheses
-        )
-
-    def export(self) -> BatchedBOCD:
-        return self.multi.export(self.group)
-
-
 def noise_scale(series: np.ndarray) -> float:
     """Robust per-step noise estimate: MAD of first differences.
 
@@ -1293,7 +677,7 @@ except ImportError:  # pragma: no cover
 #
 # The fleet screening loop talks to an *instance* implementing the batched
 # interface below; FleetDetect / ControlPlane select a backend *factory*
-# (scalar / batched-numpy / pallas) instead of hard-wiring BatchedBOCD, so
+# (scalar / batched / pallas) instead of hard-wiring BatchedBOCD, so
 # implementations stay interchangeable and equivalence-tested from one
 # registry.
 # ----------------------------------------------------------------------
@@ -1455,14 +839,12 @@ class PallasScreening(ScreeningBackendFactory):
         return PallasBOCD(n_series, **kwargs)
 
 
-#: Registry enumerated by the backend-equivalence tests; ``numpy`` is an
-#: alias for the vectorized numpy implementation.
+#: Registry enumerated by the backend-equivalence tests.
 SCREENING_BACKENDS: dict[str, ScreeningBackendFactory] = {
     "scalar": ScalarScreening(),
     "batched": BatchedScreening(),
     "pallas": PallasScreening(),
 }
-SCREENING_BACKENDS["numpy"] = SCREENING_BACKENDS["batched"]
 
 
 def select_backend(name: str | None = None) -> ScreeningBackendFactory:
@@ -1485,38 +867,14 @@ def select_backend(name: str | None = None) -> ScreeningBackendFactory:
         ) from None
 
 
-class _ClassShim(ScreeningBackendFactory):
-    """Deprecation shim: wraps a backend *class* passed where a factory
-    instance is now expected (the pre-backend-API constructor style)."""
-
-    def __init__(self, cls: type) -> None:
-        self._cls = cls
-        self.name = getattr(cls, "__name__", "class")
-
-    def make(self, n_series: int, **kwargs) -> ScreeningBackend:
-        return self._cls(n_series, **kwargs)
-
-
 def resolve_screening_backend(spec) -> ScreeningBackendFactory:
-    """Accept a backend name, ``None``/``"auto"``, a factory instance, or
-    (deprecated, with a warning) a backend class such as ``BatchedBOCD``."""
+    """Accept a backend name, ``None``/``"auto"``, or a factory: any
+    instance with a ``make`` method, subclass of
+    :class:`ScreeningBackendFactory` or not."""
     if spec is None or isinstance(spec, str):
         return select_backend(spec)
-    if isinstance(spec, type):
-        warnings.warn(
-            "passing a screening backend class is deprecated; pass a "
-            "ScreeningBackendFactory instance or a registry name "
-            f"(e.g. {sorted(set(SCREENING_BACKENDS))!r})",
-            DeprecationWarning,
-            stacklevel=2,
-        )
-        if spec is BatchedBOCD:
-            return SCREENING_BACKENDS["batched"]
-        if spec is BOCD:
-            return SCREENING_BACKENDS["scalar"]
-        return _ClassShim(spec)
-    if isinstance(spec, ScreeningBackendFactory) or hasattr(spec, "make"):
+    if not isinstance(spec, type) and hasattr(spec, "make"):
         return spec
     raise TypeError(
-        f"screening backend must be a name, factory, or class; got {spec!r}"
+        f"screening backend must be a name or a factory; got {spec!r}"
     )

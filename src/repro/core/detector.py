@@ -764,17 +764,10 @@ class FleetDetect:
     #: screening backend: a registry name (``"scalar"`` / ``"batched"`` /
     #: ``"pallas"``), ``"auto"`` (Pallas where jax compiles it, vectorized
     #: numpy elsewhere — :func:`repro.core.bocd.select_backend`), or a
-    #: :class:`repro.core.bocd.ScreeningBackendFactory` instance. Passing a
-    #: backend *class* (the pre-backend-API style) still works but warns.
+    #: factory: any instance with a ``make`` method
+    #: (:class:`repro.core.bocd.ScreeningBackendFactory` or duck-typed).
+    #: A backend *class* raises ``TypeError``.
     backend: object = "auto"
-    #: fuse all warmed cohorts into one :class:`repro.core.bocd.MultiBOCD`
-    #: frontier so each tick runs ONE batched update instead of one per
-    #: cohort (bit-identical per column — see MultiBOCD's contract). Only
-    #: takes effect on the vectorized numpy backend; the scalar and pallas
-    #: backends keep the per-cohort path. Off by default — the campaign
-    #: engine (scenarios/engine.py) opts in where the fused frontier's
-    #: snapshot/restore support pays for itself.
-    fused: bool = False
     #: last re-tune's chosen values (None until the first retune); the
     #: control plane mirrors this into its typed event log as ScreenTuning
     last_tuning: dict | None = field(init=False, default=None)
@@ -786,10 +779,6 @@ class FleetDetect:
 
     def __post_init__(self) -> None:
         self._backend = bocd.resolve_screening_backend(self.backend)
-        self._fused = bool(self.fused) and isinstance(
-            self._backend, bocd.BatchedScreening
-        )
-        self._multi = bocd.MultiBOCD() if self._fused else None
         self._hazard0 = self.hazard
         self._flags_total = 0
         self._worker_ticks = 0
@@ -916,53 +905,30 @@ class FleetDetect:
         self._cohorts = [merged] + [
             c for c in self._cohorts if c.batch is None
         ]
-        if self._fused:
-            self._rebuild_multi()
-
-    def _rebuild_multi(self) -> None:
-        """Re-absorb every warmed cohort into a fresh fused frontier (after
-        consolidation replaced the warmed batches with one standalone)."""
-        self._multi = bocd.MultiBOCD()
-        for cohort in self._cohorts:
-            batch = cohort.batch
-            if batch is None:
-                continue
-            if isinstance(batch, bocd.MultiGroupHandle):
-                batch = batch.export()
-            cohort.batch = self._multi.absorb(batch)
 
     # -- snapshot / restore --------------------------------------------
     def snapshot(self) -> dict:
         """Full mutable state as private copies (engine fork support).
 
-        Cohort batches are encoded as ``None`` (warming), a standalone
-        backend snapshot, or the index of their group inside the fused
-        frontier; backends without snapshot support (the scalar fan-out)
+        A cohort's batch is encoded as ``None`` (warming) or its backend's
+        snapshot; backends without snapshot support (the scalar fan-out)
         raise so callers can fall back to fresh execution.
         """
         cohorts: list[dict] = []
-        group_index: dict[int, int] = {}
-        if self._multi is not None:
-            group_index = {
-                id(g): i for i, g in enumerate(self._multi._groups)
-            }
         for cohort in self._cohorts:
             batch: object = None
-            if isinstance(cohort.batch, bocd.MultiGroupHandle):
-                batch = ("multi", group_index[id(cohort.batch.group)])
-            elif cohort.batch is not None:
+            if cohort.batch is not None:
                 if not hasattr(cohort.batch, "snapshot"):
                     raise NotImplementedError(
                         "screening backend "
                         f"{type(cohort.batch).__name__} has no snapshot()"
                     )
-                batch = ("batch", cohort.batch.snapshot())
+                batch = cohort.batch.snapshot()
             cohorts.append(
                 {"cols": list(cohort.cols), "start": cohort.start,
                  "batch": batch}
             )
         return {
-            "fused": self._fused,
             "hazard": self.hazard,
             "max_hypotheses": self.max_hypotheses,
             "adapt_every": self.adapt_every,
@@ -981,17 +947,12 @@ class FleetDetect:
             "ewma_age": self._ewma_age.copy(),
             "ewma_count": self._ewma_count.copy(),
             "cohorts": cohorts,
-            "multi": (
-                self._multi.snapshot() if self._multi is not None else None
-            ),
         }
 
     def restore(self, snap: dict) -> None:
         """Reinstate :meth:`snapshot` state; the instance must have been
         built with the same constructor constants (backend, windows,
         thresholds) — only mutable state is carried in the snapshot."""
-        if snap["fused"] != self._fused:
-            raise ValueError("snapshot fused mode differs from instance")
         self.hazard = snap["hazard"]
         self.max_hypotheses = snap["max_hypotheses"]
         self.adapt_every = snap["adapt_every"]
@@ -1011,30 +972,18 @@ class FleetDetect:
         self._ewma = snap["ewma"].copy()
         self._ewma_age = snap["ewma_age"].copy()
         self._ewma_count = snap["ewma_count"].copy()
-        if snap["multi"] is not None:
-            if self._multi is None:
-                self._multi = bocd.MultiBOCD()
-            self._multi.restore(snap["multi"])
         self._cohorts = []
         for rec in snap["cohorts"]:
             cohort = _Cohort(cols=list(rec["cols"]), start=rec["start"])
-            batch = rec["batch"]
-            if batch is not None:
-                kind, payload = batch
-                if kind == "multi":
-                    cohort.batch = bocd.MultiGroupHandle(
-                        self._multi, self._multi._groups[payload]
-                    )
-                else:
-                    fresh = self._backend.make(
-                        len(cohort.cols),
-                        hazard=self.hazard,
-                        mu0=np.zeros(len(cohort.cols)),
-                        cp_threshold=self.cp_threshold,
-                        max_hypotheses=self.max_hypotheses,
-                    )
-                    fresh.restore(payload)
-                    cohort.batch = fresh
+            if rec["batch"] is not None:
+                cohort.batch = self._backend.make(
+                    len(cohort.cols),
+                    hazard=self.hazard,
+                    mu0=np.zeros(len(cohort.cols)),
+                    cp_threshold=self.cp_threshold,
+                    max_hypotheses=self.max_hypotheses,
+                )
+                cohort.batch.restore(rec["batch"])
             self._cohorts.append(cohort)
 
     # ------------------------------------------------------------------
@@ -1066,25 +1015,6 @@ class FleetDetect:
                 self._ewma += alpha * (times - self._ewma)
                 self._ewma_age += 1
         out: list[FleetFlag] = []
-        if self._fused:
-            # Fused pre-pass: warm any ready cohorts into the shared
-            # MultiBOCD frontier, then advance every group with ONE fused
-            # update instead of one batched update per cohort.
-            for cohort in self._cohorts:
-                if cohort.batch is None and n - cohort.start >= self.warmup:
-                    cohort.batch = self._multi.absorb(
-                        self._warm_cohort(cohort, n)
-                    )
-            if self._multi.n_series:
-                x = np.empty(self._multi.n_series)
-                for cohort in self._cohorts:
-                    if cohort.batch is not None:
-                        cols = cohort.cols_array()
-                        x[cohort.batch.cols] = (
-                            times[cols] / self._scale[cols]
-                        )
-                with span("fleet.bocd_update", tick=tick):
-                    self._multi.update(x)
         with span("fleet.drift", tick=tick):
             drift_ref_mean, drift_cur_mean = self._drift_means(n)
         for cohort in self._cohorts:
@@ -1093,9 +1023,8 @@ class FleetDetect:
                 if n - cohort.start < self.warmup:
                     continue
                 cohort.batch = self._warm_cohort(cohort, n)
-            if not self._fused:
-                with span("fleet.bocd_update", tick=tick):
-                    cohort.batch.update(times[cols] / self._scale[cols])
+            with span("fleet.bocd_update", tick=tick):
+                cohort.batch.update(times[cols] / self._scale[cols])
             if i - cohort.start <= self.recent_window:
                 continue
             with span("fleet.posterior", tick=tick):
@@ -1174,7 +1103,7 @@ class FleetDetect:
         """Full-width reference/current trailing means for the drift screen,
         computed once per tick and column-sliced per cohort (bit-identical
         to the per-cohort means for cohorts of >= 2 workers; single-worker
-        cohorts recompute on the per-cohort shape — see MultiBOCD)."""
+        cohorts recompute on the per-cohort shape)."""
         if not self.drift_ref:
             return None, None
         lag_lo = n - self.drift_ref - self.drift_ref_window

@@ -12,7 +12,7 @@ once and forks at the divergence point:
 * the **faults** leg runs fresh and is *recorded*: per-tick samples, each
   job's cumulative progress, stall count and jitter-draw count, joins and
   finishes. Every plane-mode prefix rides this recording.
-* a **shared plane leg** (falcon screening semantics, fused fleet screen)
+* a **shared plane leg** (falcon screening semantics, default fleet screen)
   is driven by the recorded samples with lazily-materialized job adapters,
   taking a rolling :meth:`ControlPlane.snapshot` each tick. It stops at
   the first event outside {Observation, Membership, ScreenTuning} — the
@@ -280,10 +280,7 @@ class CampaignEngine:
         )
 
     def _fleet_kwargs(self, mode: str) -> dict:
-        # The engine's legs always run the fused (single-launch) fleet
-        # screen — bit-equivalent to the per-cohort default and cheaper
-        # per tick, and forks restore into the same layout.
-        kw: dict = {"fused": True}
+        kw: dict = {}
         if mode == "falcon" and self.spec.preset.adapt_every:
             kw["adapt_every"] = self.spec.preset.adapt_every
         return kw

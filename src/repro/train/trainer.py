@@ -20,8 +20,7 @@ The trainer's only mitigation role is mirroring results into its JAX-side
 state: S2 allocations into the adaptive train step's trip counts, S4 into
 an in-memory checkpoint restore; the runtime analogue of S3 (mesh device
 permutation + state re-put) is exposed as :func:`remap_mesh` for
-multi-device runs. ``FalconTrainer._apply_strategy`` remains as a thin
-deprecation shim over the registry.
+multi-device runs.
 
 Each step is a wall-clock step span (:mod:`repro.obs.host`), ``train.step``,
 holding ``train.batch`` (``make_batch`` and the upload),
@@ -46,7 +45,6 @@ import contextlib
 import os
 import tempfile
 import time
-import warnings
 from dataclasses import dataclass, field
 from typing import Any
 
@@ -57,7 +55,6 @@ from repro.cluster.injector import FailSlowInjector
 from repro.cluster.simulator import TrainingSimulator
 from repro.configs.base import ArchConfig
 from repro.controlplane import ControlPlane, MitigationResult
-from repro.controlplane.strategies import MitigationContext
 from repro.core.detector import FalconDetect
 from repro.core.events import Strategy, strategy_label
 from repro.core.planner import DEFAULT_OVERHEADS
@@ -204,31 +201,6 @@ class FalconTrainer:
         if self.injector is not None:
             self.injector.apply(self.perf_model.state, now)
         return self.perf_model.iteration_time()
-
-    def _apply_strategy(self, strategy: Strategy, event) -> None:
-        """Deprecated: dispatch through the control-plane strategy registry
-        (kept as a shim for pre-control-plane callers)."""
-        warnings.warn(
-            "FalconTrainer._apply_strategy is deprecated; strategies are "
-            "dispatched through repro.controlplane.StrategyRegistry",
-            DeprecationWarning,
-            stacklevel=2,
-        )
-        if self.control is None:
-            return
-        outcome = self._job.registry.dispatch(
-            strategy,
-            MitigationContext(
-                adapter=self.perf_model, event=event, now=self._wall,
-                job_id="train", injector=self.injector,
-            ),
-        )
-        self._mirror_result(
-            MitigationResult(
-                job_id="train", time=self._wall, strategy=strategy,
-                applied=outcome.applied, detail=outcome.detail,
-            )
-        )
 
     def _mirror_result(self, ev: MitigationResult) -> None:
         """Reflect a strategy's modeled effects into the JAX-side state."""

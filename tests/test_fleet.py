@@ -622,17 +622,13 @@ def test_fleet_detect_backend_flag_parity():
 
 def test_screening_backend_registry_resolution():
     assert bocd.select_backend("batched").name == "batched"
-    assert bocd.select_backend("numpy").name == "batched"  # alias
     auto = bocd.select_backend(None)
     assert auto.name == ("pallas" if pallas_compiled() else "batched")
     with pytest.raises(ValueError, match="unknown screening backend"):
         bocd.select_backend("fpga")
-    # factory instances pass through; backend classes warn but still work
+    # factory instances pass through
     fac = bocd.SCREENING_BACKENDS["scalar"]
     assert bocd.resolve_screening_backend(fac) is fac
-    with pytest.deprecated_call():
-        shim = bocd.resolve_screening_backend(bocd.BatchedBOCD)
-    assert shim.name == "batched"
 
 
 def _faulted_sim(n_devices=512, seed=0):
@@ -684,7 +680,6 @@ def test_reduction_backend_resolution_and_sim_knob():
     auto = S.resolve_reduction_backend(None, job)
     assert auto is None if not pallas_compiled() else auto.name == "pallas"
     assert S.resolve_reduction_backend("vectorized", job) is None
-    assert S.resolve_reduction_backend("numpy", job) is None
     assert S.resolve_reduction_backend("reference", job).name == "reference"
     with pytest.raises(ValueError, match="unknown reduction backend"):
         S.select_reduction_backend("abacus")
@@ -736,95 +731,148 @@ def test_reduction_backend_degenerate_topology_is_reported(monkeypatch):
     assert hybrid.reduction_name == "vectorized"
 
 
-# ------------------------------------------- fused multi-cohort screen
-def _churny_screen(seed, adapt, fused, ticks=420, n0=6):
-    """Drive one FleetDetect through joins/leaves/step-faults; return the
-    flag log plus the observable tuning state."""
-    rng = np.random.default_rng(seed)
-    fd = FleetDetect(
-        n_workers=n0, adapt_every=adapt, backend="batched", fused=fused
-    )
-    level = np.ones(fd.n_workers)
-    flags_log = []
-    for t in range(ticks):
-        if t in (120, 180):
-            fd.add_worker()
-            level = np.append(level, 1.0)
-        if t == 260 and fd.n_workers > 4:
-            fd.remove_worker(2)
-            level = np.delete(level, 2)
-        if t in (90, 150, 230, 300, 360):
-            level[(t // 30) % fd.n_workers] *= 1.6
-        if t == 330:
-            level[0] *= 0.6
+# ------------------------------------------- snapshot / restore, factories
+def _steady_step(fd, level, t):
+    """Step faults on a fixed membership."""
+    if t in (90, 150, 230):
+        level[(t // 30) % fd.n_workers] *= 1.5
+    return level
+
+
+def _churn_step(fd, level, t):
+    """Joins at 120 and 180, a leave at 260, step faults up and down."""
+    if t in (120, 180):
+        fd.add_worker()
+        level = np.append(level, 1.0)
+    if t == 260 and fd.n_workers > 4:
+        fd.remove_worker(2)
+        level = np.delete(level, 2)
+    if t in (90, 150, 230, 300, 360):
+        level[(t // 30) % fd.n_workers] *= 1.6
+    if t == 330:
+        level[0] *= 0.6
+    return level
+
+
+def _drive(fd, step, level, rng, t0, t1, out):
+    """Tick ``fd`` over ``[t0, t1)``, logging every flag; returns the
+    level vector and the most cohorts seen at once."""
+    peak = fd.n_cohorts
+    for t in range(t0, t1):
+        level = step(fd, level, t)
         x = level * (1.0 + 0.02 * rng.standard_normal(fd.n_workers))
-        flags_log.append([
+        out.append([
             (f.worker, f.change_point.index, f.change_point.probability,
              f.change_point.mean_before, f.change_point.mean_after)
             for f in fd.tick(x)
         ])
-    return flags_log, fd._scale.copy(), fd._ewma.copy(), fd.hazard, \
-        fd.max_hypotheses
+        peak = max(peak, fd.n_cohorts)
+    return level, peak
 
 
 @pytest.mark.parametrize("adapt", [0, 50])
-@pytest.mark.parametrize("seed", [3, 7])
-def test_fused_screen_bitwise_matches_per_cohort(adapt, seed):
-    """The single-launch fused frontier is not approximately the per-cohort
-    screen — it IS the per-cohort screen, bitwise, through membership churn
-    and adaptive retunes (the campaign engine's forks rely on this)."""
-    fl0, sc0, ew0, hz0, mh0 = _churny_screen(seed, adapt, fused=False)
-    fl1, sc1, ew1, hz1, mh1 = _churny_screen(seed, adapt, fused=True)
-    assert fl0 == fl1
-    assert np.array_equal(sc0, sc1, equal_nan=True)
-    assert np.array_equal(ew0, ew1, equal_nan=True)
-    assert (hz0, mh0) == (hz1, mh1)
-
-
-@pytest.mark.parametrize("fused", [False, True])
-def test_fleet_snapshot_restore_tail_equivalence(fused):
+@pytest.mark.parametrize(
+    "schedule, seed, cut, end, max_cohorts",
+    [
+        ("steady", 9, 100, 180, 4),
+        ("churn", 3, 200, 420, 2),
+        ("churn", 7, 200, 420, 2),
+    ],
+)
+def test_fleet_snapshot_restore_tail_equivalence(
+    schedule, seed, cut, end, max_cohorts, adapt
+):
     """A fresh FleetDetect restored from snapshot() continues bitwise
-    identically to the instance that kept running."""
-    def drive(fd, level, rng, t0, t1, out):
-        for t in range(t0, t1):
-            if t in (90, 150, 230):
-                level[(t // 30) % fd.n_workers] *= 1.5
-            x = level * (1.0 + 0.02 * rng.standard_normal(fd.n_workers))
-            out.append([
-                (f.worker, f.change_point.index) for f in fd.tick(x)
-            ])
-
-    rng = np.random.default_rng(9)
-    fd = FleetDetect(
-        n_workers=6, backend="batched", fused=fused, adapt_every=50
-    )
-    level = np.ones(6)
-    pre: list = []
-    drive(fd, level, rng, 0, 100, pre)
+    identically to the instance that kept running — on the churn schedule
+    also after joins and a consolidation before the cut and a leave after
+    it (the campaign engine's forks rely on this)."""
+    step = _steady_step if schedule == "steady" else _churn_step
+    kw = dict(backend="batched", adapt_every=adapt, max_cohorts=max_cohorts)
+    rng = np.random.default_rng(seed)
+    fd = FleetDetect(n_workers=6, **kw)
+    level, peak = _drive(fd, step, np.ones(6), rng, 0, cut, [])
+    if schedule == "churn":
+        assert fd.n_cohorts < peak  # consolidated before the snapshot
     snap = fd.snapshot()
     rng_state = rng.bit_generator.state
     level_snap = level.copy()
     cont_a: list = []
-    drive(fd, level, rng, 100, 180, cont_a)
+    _drive(fd, step, level, rng, cut, end, cont_a)
 
-    fd2 = FleetDetect(
-        n_workers=6, backend="batched", fused=fused, adapt_every=50
-    )
+    fd2 = FleetDetect(n_workers=6, **kw)
     fd2.restore(snap)
-    rng2 = np.random.default_rng(9)
+    rng2 = np.random.default_rng(seed)
     rng2.bit_generator.state = rng_state
     cont_b: list = []
-    drive(fd2, level_snap, rng2, 100, 180, cont_b)
+    _drive(fd2, step, level_snap, rng2, cut, end, cont_b)
+    assert any(cont_a)
     assert cont_a == cont_b
+    assert np.array_equal(fd._scale, fd2._scale, equal_nan=True)
+    assert np.array_equal(fd._ewma, fd2._ewma, equal_nan=True)
+    assert (fd.hazard, fd.max_hypotheses) == (fd2.hazard, fd2.max_hypotheses)
 
 
-def test_fleet_restore_rejects_fused_mismatch():
-    fd = FleetDetect(n_workers=4, backend="batched", fused=True)
-    fd.tick(np.ones(4))
-    snap = fd.snapshot()
-    other = FleetDetect(n_workers=4, backend="batched", fused=False)
-    with pytest.raises(ValueError, match="fused"):
-        other.restore(snap)
+class _CountingFactory:
+    """A duck-typed screening factory, no ScreeningBackendFactory base
+    (like the benchmark's recording factory): keeps what it makes."""
+
+    name = "counting"
+
+    def __init__(self) -> None:
+        self.made: list = []
+
+    def make(self, n_series: int, **kwargs):
+        inst = bocd.BatchedBOCD(n_series, **kwargs)
+        self.made.append(inst)
+        return inst
+
+
+def test_fleet_detect_makes_every_cohort_through_its_factory():
+    """A joining cohort's warm-up, consolidate() and restore() each build
+    their batch through the caller's factory, and the restored copy ticks
+    on like the original."""
+    rng = np.random.default_rng(5)
+    fac = _CountingFactory()
+    fd = FleetDetect(n_workers=4, backend=fac, max_cohorts=None)
+    for _ in range(40):
+        fd.tick(1.0 + 0.02 * rng.standard_normal(fd.n_workers))
+    assert [b.n_series for b in fac.made] == [4]
+    fd.add_worker()  # warms 8 ticks later
+    for _ in range(8):
+        fd.tick(1.0 + 0.02 * rng.standard_normal(fd.n_workers))
+    assert [b.n_series for b in fac.made] == [4, 1]
+    assert [c.batch for c in fd._cohorts] == fac.made
+
+    fd.consolidate()
+    assert [b.n_series for b in fac.made] == [4, 1, 5]
+    assert fd.n_cohorts == 1 and fd._cohorts[0].batch is fac.made[-1]
+
+    fac2 = _CountingFactory()
+    fd2 = FleetDetect(n_workers=5, backend=fac2, max_cohorts=None)
+    fd2.restore(fd.snapshot())
+    assert [b.n_series for b in fac2.made] == [5]
+    assert fd2._cohorts[0].batch is fac2.made[0]
+    level = np.ones(5)
+    flags: list = []
+    for t in range(60):
+        if t == 20:
+            level[3] *= 1.5
+        x = level * (1.0 + 0.02 * rng.standard_normal(5))
+        got = [(f.worker, f.change_point.index) for f in fd.tick(x)]
+        assert got == [(f.worker, f.change_point.index) for f in fd2.tick(x)]
+        flags += got
+    assert [w for w, _ in flags] == [3]
+    assert len(fac.made) == 3 and len(fac2.made) == 1
+
+
+def test_resolve_screening_backend_rejects_a_class():
+    """Only names and factory instances select a backend: a backend or
+    factory class raises instead of being wrapped."""
+    for cls in (bocd.BatchedBOCD, bocd.BOCD, bocd.BatchedScreening):
+        with pytest.raises(TypeError, match="a name or a factory"):
+            bocd.resolve_screening_backend(cls)
+    with pytest.raises(TypeError):
+        FleetDetect(n_workers=2, backend=bocd.BatchedBOCD)
 
 
 def test_watchdog_snapshot_roundtrip():
